@@ -35,8 +35,7 @@ import sys, json, time
 import jax, jax.numpy as jnp, numpy as np
 from repro.conv import plan_conv
 from repro.compat import make_mesh
-from repro.launch.roofline import parse_collectives, roofline_terms, \
-    PEAK_FLOPS, HBM_BW
+from repro.launch.roofline import parse_collectives
 mesh = make_mesh((%(nd)d, %(nm)d), ("data", "model"))
 spec = json.loads(sys.argv[1])
 variant = spec["variant"]
@@ -117,6 +116,7 @@ VARIANTS = ("wfft", "nfft", "nfft_ep_fused", "nfft_ep_unfused",
 def run(layer, variant, *, ndev, nd, nm, measure, reps=3):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"        # emulated host mesh, by design
     spec = dict(layer, variant=variant, measure=measure, reps=reps)
     worker = _WORKER % dict(ndev=ndev, nd=nd, nm=nm)
     r = subprocess.run([sys.executable, "-c", worker, json.dumps(spec)],

@@ -20,8 +20,6 @@ import subprocess
 import sys
 
 _WORKER = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys, json, time
 import jax, jax.numpy as jnp, numpy as np
 from repro.conv import plan_conv
@@ -56,6 +54,9 @@ print("RESULT" + json.dumps(out))
 def run_layer(name, B, C, Co, H, W, kh, pad, reps=5):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    # the 8 "NUMA nodes" are emulated host devices: CPU by design
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     spec = dict(B=B, C=C, Co=Co, H=H, W=W, kh=kh, pad=pad, reps=reps)
     r = subprocess.run([sys.executable, "-c", _WORKER, json.dumps(spec)],
                        env=env, capture_output=True, text=True, timeout=900)

@@ -12,8 +12,7 @@ winner alongside its timing (see ``benchmarks.bench_schema`` for the
 tolerant schema every consumer shares).  The CI perf gate
 (``benchmarks.compare_baseline``) diffs this file against the committed
 ``benchmarks/BENCH_baseline.json``.  Full-scale (arch x shape x mesh)
-numbers come from the dry-run (`repro.launch.dryrun --all`) and are
-summarised in EXPERIMENTS.md.
+numbers come from the dry-run (`repro.launch.dryrun --all`).
 """
 from __future__ import annotations
 
@@ -72,6 +71,13 @@ def main(argv=None) -> dict:
                          "the benchmark run ('' disables)")
     args = ap.parse_args(argv)
 
+    from repro.launch.env import compile_cache_dir
+    compile_cache_dir()
+    # the cold-start workers need the device in fresh processes: run them
+    # while this process has not touched JAX (a parent holding a TPU
+    # would leave them none)
+    coldstart = _coldstart_rows(quick=args.quick)
+
     tee = _Tee(sys.stdout)
     sys.stdout = tee
     try:
@@ -95,7 +101,7 @@ def main(argv=None) -> dict:
     rows = parse_csv_rows(tee.captured.getvalue())
     rows.update(_overlap_rows(quick=args.quick))
     rows.update(_serve_rows(quick=args.quick))
-    rows.update(_coldstart_rows(quick=args.quick))
+    rows.update(coldstart)
     if args.tuned:
         rows.update(_tuned_rows(quick=args.quick))
     if args.json_out:
@@ -217,8 +223,10 @@ def _overlap_rows(quick: bool = True) -> dict:
     """Comm/compute-overlapped nfft vs the synchronous baseline on a
     4-device emulated NUMA mesh (device-count forcing + latency-hiding
     scheduler flags from ``repro.launch.env``; subprocess so the parent
-    keeps its real device).  Dict entries record the slab count next to
-    the timing."""
+    keeps its real device).  The worker is CPU emulation by design and
+    runs with ``JAX_PLATFORMS=cpu``, so it never competes with the parent
+    for an accelerator.  Dict entries record the slab count next to the
+    timing."""
     import os
     import subprocess
 
@@ -236,6 +244,7 @@ def _overlap_rows(quick: bool = True) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     env["XLA_FLAGS"] = xla_flags(ndev)
+    env["JAX_PLATFORMS"] = "cpu"        # emulated host devices, by design
     print(f"# overlap: nfft sub-slab pipelines on a {ndev}-device emulated "
           "mesh — name,us_per_call,overlap")
     out = {}
@@ -310,7 +319,8 @@ def _coldstart_rows(quick: bool = True) -> dict:
     live-planned vs rehydrated from the AOT plan artifact the live
     worker exported (``repro.conv.export``).  Two subprocesses so both
     sides pay real process cold-start — no warm jax caches leak in from
-    the parent."""
+    the parent.  Each worker runs on the real device, so ``main`` calls
+    this before the parent imports JAX."""
     import os
     import subprocess
     import tempfile

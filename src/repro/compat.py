@@ -1,10 +1,8 @@
-"""Version compatibility shims for the jax API surface.
+"""The two jax entry points every mesh and shard_map goes through.
 
-The repo targets current jax (``jax.shard_map`` with ``check_vma``,
-``jax.make_mesh(..., axis_types=...)``); seed environments may carry an
-older release where ``shard_map`` lives in ``jax.experimental`` (with
-``check_rep``) and ``make_mesh`` has no ``axis_types``.  Everything that
-builds meshes or shard_maps goes through these two wrappers.
+Written for the installed jax (0.9): ``jax.shard_map`` with ``check_vma``
+and ``jax.make_mesh(..., axis_types=...)``.  Call sites use these names,
+so a later jax API change is one edit here.
 """
 from __future__ import annotations
 
@@ -12,31 +10,21 @@ import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """jax.shard_map with replication checking off, any jax version."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """jax.shard_map with replication checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """jax.make_mesh with Auto axis types where supported."""
+    """jax.make_mesh with Auto axis types."""
     kwargs = {} if devices is None else {"devices": devices}
-    if hasattr(jax.sharding, "AxisType"):
-        kwargs["axis_types"] = \
-            (jax.sharding.AxisType.Auto,) * len(axis_names)
-    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names), **kwargs)
 
 
 def jaxpr_types():
-    """The (Jaxpr, ClosedJaxpr) classes, wherever this jax version keeps
-    them (``jax.extend.core`` on current jax, ``jax.core`` on older
-    releases).  Used by the static analyzer to recurse into sub-jaxprs."""
-    try:
-        from jax.extend import core as xcore
-        return xcore.Jaxpr, xcore.ClosedJaxpr
-    except (ImportError, AttributeError):
-        from jax import core as jcore
-        return jcore.Jaxpr, jcore.ClosedJaxpr
+    """The (Jaxpr, ClosedJaxpr) classes, which the static analyzer uses to
+    recurse into sub-jaxprs."""
+    from jax.extend import core as xcore
+    return xcore.Jaxpr, xcore.ClosedJaxpr

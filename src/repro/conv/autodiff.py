@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.conv.epilogue import ACTIVATIONS, activation_vjp, bias_grad
+from repro.core.dft import PRECISION
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -97,7 +98,7 @@ def _dk_direct(plan, x, dz, k_dtype):
         xp.transpose(1, 0, 2, 3),                  # (C, B, Hp, Wp)
         dz.transpose(1, 0, 2, 3),                  # (C', B, Ho, Wo)
         window_strides=(1, 1), padding="VALID",
-        dimension_numbers=("NCHW", "OIHW", "NCHW"),
+        dimension_numbers=("NCHW", "OIHW", "NCHW"), precision=PRECISION,
     ).transpose(1, 0, 2, 3).astype(k_dtype)        # (C', C, kh, kw)
 
 
@@ -132,39 +133,38 @@ pipeline_conv.defvjp(_fwd, _bwd)
 # --------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def prepared_conv(prepared, x, bias=None, residual=None):
-    """Execute a ``PreparedConv`` with grads w.r.t. ``x`` (and bias /
-    residual, when the epilogue carries them) defined by the same
-    transposed-plan VJP as ``pipeline_conv`` — which also shields the
-    Pallas kernels from being differentiated through, so prepared
-    ``fft-pallas`` trains its inputs too.  (The conv kernel is frozen in a
-    prepared plan; there is no dk.)"""
-    plan = prepared.plan
-    return _pipeline(plan).execute(plan, x, prepared.state, bias=bias,
+def prepared_conv(plan, state, kernel, x, bias=None, residual=None):
+    """Execute a prepared plan (``state`` is its transformed kernel) with
+    grads w.r.t. ``x`` (and bias / residual, when the epilogue carries
+    them) defined by the same transposed-plan VJP as ``pipeline_conv`` —
+    which also shields the Pallas kernels from being differentiated
+    through, so prepared ``fft-pallas`` trains its inputs too.  The conv
+    kernel is frozen in a prepared plan: ``state`` and ``kernel`` get no
+    cotangent.  Both are primal arguments, not static ones, so a jitted
+    caller can pass them in as traced inputs."""
+    return _pipeline(plan).execute(plan, x, state, bias=bias,
                                    residual=residual)
 
 
-def _prep_fwd(prepared, x, bias, residual):
-    ep = prepared.plan.epilogue
+def _prep_fwd(plan, state, kernel, x, bias, residual):
+    ep = plan.epilogue
     if ep.activation == "none":
-        return prepared_conv(prepared, x, bias, residual), \
-            (bias, residual, None)
-    pre = dataclasses.replace(prepared, plan=_pre_activation_plan(
-        prepared.plan))
-    z = prepared_conv(pre, x, bias, residual)
-    return ACTIVATIONS[ep.activation](z), (bias, residual, z)
+        return prepared_conv(plan, state, kernel, x, bias, residual), \
+            (kernel, bias, residual, None)
+    z = prepared_conv(_pre_activation_plan(plan), state, kernel, x, bias,
+                      residual)
+    return ACTIVATIONS[ep.activation](z), (kernel, bias, residual, z)
 
 
-def _prep_bwd(prepared, res, dy):
-    bias, residual, z = res
-    plan = prepared.plan
+def _prep_bwd(plan, res, dy):
+    kernel, bias, residual, z = res
     ep = plan.epilogue
     dz = dy if z is None else activation_vjp(ep, z, dy)
-    dx = _dx_via_transposed_plan(plan, prepared.kernel, dz)
+    dx = _dx_via_transposed_plan(plan, kernel, dz)
     dbias = bias_grad(dz).astype(bias.dtype) if ep.bias else None
     dres = dz.astype(residual.dtype) if ep.residual else None
     # execution returns x.dtype, so dy carries the input dtype
-    return dx.astype(dy.dtype), dbias, dres
+    return None, None, dx.astype(dy.dtype), dbias, dres
 
 
 prepared_conv.defvjp(_prep_fwd, _prep_bwd)

@@ -222,11 +222,8 @@ def reset() -> None:
 # --------------------------------------------------------------------------
 
 def _device_kind() -> str:
-    try:
-        import jax
-        return str(jax.devices()[0].device_kind).replace("|", "/")
-    except Exception:
-        return "unknown"
+    import jax
+    return str(jax.devices()[0].device_kind).replace("|", "/")
 
 
 def _jax_version() -> str:
@@ -477,9 +474,10 @@ def tune(spec, k_shape=None, *, padding=None, delta: Optional[int] = None,
          budget: Optional[float] = None,
          reps: Optional[int] = None) -> TunedConfig:
     """Return the winning config for this spec: warm-cache hit, measured
-    sweep, or cost-model fallback (measurement disabled / every candidate
-    failed), in that order.  Only measured winners are persisted — a
-    cost-model fallback stays cold so enabling measurement later re-tunes.
+    sweep, or cost-model fallback (measurement disabled), in that order.
+    A candidate that raises fails the sweep with its error.  Only measured
+    winners are persisted — a cost-model fallback stays cold so enabling
+    measurement later re-tunes.
 
     ``spec`` is the same first positional ``plan_conv`` takes: either a
     ``ConvSpec`` (geometry + padding + delta in one object) or the input
@@ -548,16 +546,15 @@ def tune(spec, k_shape=None, *, padding=None, delta: Optional[int] = None,
                 data_axis=data_axis, model_axis=model_axis,
                 replicate_kernel_transform=replicate_kernel_transform,
                 reps=reps)
-        except Exception:
-            continue                    # infeasible candidate (skip)
+        except Exception as e:
+            # every candidate is a legal plan: one that fails to compile
+            # or run is an engine fault, never a silently skipped point
+            raise RuntimeError(
+                f"autotune candidate {cand} failed on "
+                f"{_device_kind()}: {type(e).__name__}: {e}") from e
         if best is None or us < best.us_per_call:
             best = dataclasses.replace(cand, us_per_call=us,
                                        source="measured")
-    if best is None:
-        with _lock:
-            _fallbacks += 1
-        return _cost_model_config(spec, schedule, mesh, three_m,
-                                  spectrum, overlap, bm, bn, bk, dft_bt)
     with _lock:
         _measured += 1
     store.put(key, best)

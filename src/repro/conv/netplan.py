@@ -44,6 +44,8 @@ import dataclasses
 import warnings
 from typing import Any, Callable, Mapping, Optional, Sequence, Union
 
+import jax
+
 from repro.conv.epilogue import Epilogue
 from repro.conv.plan import ConvPlan, PreparedConv, plan_conv
 
@@ -78,7 +80,8 @@ class PreparedNetwork:
 
     Mapping-like: ``prepared["conv1"](x, bias=...)``.  Every layer shares
     one ``weights_version``; re-prepare the network (not a layer) after a
-    weight update.
+    weight update.  A pytree of ``PreparedConv`` layers, so a jitted
+    forward takes it as an argument.
     """
     layers: "collections.OrderedDict[str, PreparedConv]"
     weights_version: Any = None
@@ -94,6 +97,15 @@ class PreparedNetwork:
 
     def items(self):
         return self.layers.items()
+
+
+jax.tree_util.register_pytree_node(
+    PreparedNetwork,
+    lambda n: (tuple(n.layers.values()),
+               (tuple(n.layers), n.weights_version)),
+    lambda aux, children: PreparedNetwork(
+        layers=collections.OrderedDict(zip(aux[0], children)),
+        weights_version=aux[1]))
 
 
 @dataclasses.dataclass(frozen=True, eq=False)

@@ -40,6 +40,8 @@ import os
 import threading
 from typing import Any, Optional
 
+import jax
+
 from repro.core.conv_spec import ConvSpec
 from repro.conv import registry
 from repro.conv import autodiff
@@ -141,7 +143,6 @@ class ConvPlan:
             raise ValueError(
                 f"plan was built for kernel {self.k_shape}, got "
                 f"{tuple(k.shape)}; call plan_conv for the new geometry")
-        import jax
         if isinstance(k, jax.core.Tracer):
             raise ValueError(
                 "plan.prepare must run outside jit/grad (it caches the "
@@ -253,6 +254,10 @@ class PreparedConv:
     paid once in ``plan.prepare``.  Pipeline backends are differentiable
     w.r.t. ``x`` (the plan-level VJP, so ``fft-pallas`` included); the
     kernel is frozen — to train it, use ``plan(x, k)``.
+
+    A pytree whose leaves are ``state`` and ``kernel``: pass it to a jitted
+    function as an argument, so the transformed kernel is an input of the
+    executable rather than a constant compiled into it.
     """
     plan: ConvPlan
     state: Any                          # pipeline G pytree, or raw k (opaque)
@@ -264,7 +269,8 @@ class PreparedConv:
         self.plan._check_epilogue_operands(bias, residual)
         be = registry.get_backend(self.plan.backend)
         if be.pipeline_factory is not None:
-            return autodiff.prepared_conv(self, x, bias, residual)
+            return autodiff.prepared_conv(self.plan, self.state, self.kernel,
+                                          x, bias, residual)
         if not self.plan.epilogue.is_noop:
             return be.execute(self.plan, x, self.state, bias=bias,
                               residual=residual)
@@ -280,6 +286,14 @@ class PreparedConv:
         program); see ``repro.conv.analyze``."""
         from repro.conv.analyze import analyze
         return analyze(self)
+
+
+jax.tree_util.register_pytree_node(
+    PreparedConv,
+    lambda p: ((p.state, p.kernel), (p.plan, p.weights_version)),
+    lambda aux, children: PreparedConv(plan=aux[0], state=children[0],
+                                       kernel=children[1],
+                                       weights_version=aux[1]))
 
 
 # --------------------------------------------------------------------------

@@ -59,7 +59,7 @@ import threading
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.compat import shard_map
 from repro.core.conv_spec import ConvSpec
@@ -209,6 +209,14 @@ def padded_sharded_spec(plan) -> ConvSpec:
         B=s.B + (-s.B) % n_data, C=s.C + (-s.C) % n_model,
         Cout=s.Cout + (-s.Cout) % n_model, H=s.H, W=s.W, kh=s.kh, kw=s.kw,
         pad_h=s.pad_h, pad_w=s.pad_w, delta=s.delta)
+
+
+def _place(pair, plan, pspec):
+    """Lay a prepared (re, im) pair out on the plan's mesh as ``pspec``,
+    so prepared execution starts from distributed slabs instead of
+    re-sharding one device's copy on every call."""
+    sharding = NamedSharding(plan.mesh, pspec)
+    return tuple(jax.device_put(t, sharding) for t in pair)
 
 
 def _maybe_cast(pair, dtype):
@@ -432,13 +440,15 @@ class NfftPipeline:
 
         The P axis is padded up to a model-axis multiple so the prepared
         slab enters shard_map P-sharded (matching the post-boundary layout
-        the a2a padding produces on the inline path).
+        the a2a padding produces on the inline path), and is placed in
+        that layout: each rank holds its own P-slab.
         """
         spec = padded_sharded_spec(plan)
         n_model = plan.mesh.shape[plan.model_axis]
         kp = _pad_axis(_pad_axis(k, 0, n_model), 1, n_model)
         Gr, Gi = stage_kernel_transform(kp, spec, plan.spectrum)
-        return _pad_axis(Gr, 0, n_model), _pad_axis(Gi, 0, n_model)
+        return _place((_pad_axis(Gr, 0, n_model), _pad_axis(Gi, 0, n_model)),
+                      plan, P(plan.model_axis, None, None))
 
     def execute(self, plan, x, G, bias=None, residual=None):
         spec = padded_sharded_spec(plan)
@@ -569,10 +579,13 @@ class WfftPipeline:
                           n_model=n_model)
 
     def prepare(self, plan, k):
+        """Stage 2, once: global (P, C, C'), placed C-sharded the way
+        execution consumes it."""
         spec = padded_sharded_spec(plan)
         n_model = plan.mesh.shape[plan.model_axis]
         kp = _pad_axis(_pad_axis(k, 0, n_model), 1, n_model)
-        return stage_kernel_transform(kp, spec, plan.spectrum)
+        return _place(stage_kernel_transform(kp, spec, plan.spectrum), plan,
+                      P(None, plan.model_axis, None))
 
     def _run(self, plan, x, args, body, extra_in_specs):
         mesh = plan.mesh

@@ -13,13 +13,25 @@ back with weight 2 (columns 0 and Nyquist with weight 1).
 
 All complex arithmetic is struct-of-arrays (separate real/imag planes);
 neither the MXU nor Pallas has a native complex dtype.
+
+Every matmul of the engine runs at ``PRECISION`` (``HIGHEST``): XLA's
+default on a TPU rounds float32 operands to bf16 for a single MXU pass,
+which would make a float32 plan a bf16 one.  A plan asks for bf16
+operands explicitly, through ``compute_dtype``.
 """
 from __future__ import annotations
 
 import functools
 
 import numpy as np
+import jax
 import jax.numpy as jnp
+
+PRECISION = jax.lax.Precision.HIGHEST
+
+
+def _ein(eq, a, b):
+    return jnp.einsum(eq, a, b, precision=PRECISION)
 
 
 @functools.lru_cache(maxsize=None)
@@ -62,11 +74,11 @@ def rfft2_tiles(x, delta: int):
     """
     Fr, Fi, Fhr, Fhi, *_ = dft_mats(delta)
     # A = F @ x  (x real): 2 real matmuls
-    Ar = jnp.einsum("uh,...hw->...uw", Fr, x)
-    Ai = jnp.einsum("uh,...hw->...uw", Fi, x)
+    Ar = _ein("uh,...hw->...uw", Fr, x)
+    Ai = _ein("uh,...hw->...uw", Fi, x)
     # T = A @ F_half^T: (Ar + iAi)(Fhr^T + iFhi^T)
-    Tr = jnp.einsum("...uw,vw->...uv", Ar, Fhr) - jnp.einsum("...uw,vw->...uv", Ai, Fhi)
-    Ti = jnp.einsum("...uw,vw->...uv", Ar, Fhi) + jnp.einsum("...uw,vw->...uv", Ai, Fhr)
+    Tr = _ein("...uw,vw->...uv", Ar, Fhr) - _ein("...uw,vw->...uv", Ai, Fhi)
+    Ti = _ein("...uw,vw->...uv", Ar, Fhi) + _ein("...uw,vw->...uv", Ai, Fhr)
     return Tr, Ti
 
 
@@ -74,10 +86,10 @@ def irfft2_tiles(Zr, Zi, delta: int):
     """Batched irfft2 via matmul. (Zr, Zi): (..., delta, delta_h) -> (..., delta, delta) real."""
     *_, Fvr, Fvi, Wr, Wi = dft_mats(delta)
     # Y = Finv @ Z (complex x complex)
-    Yr = jnp.einsum("hu,...uv->...hv", Fvr, Zr) - jnp.einsum("hu,...uv->...hv", Fvi, Zi)
-    Yi = jnp.einsum("hu,...uv->...hv", Fvr, Zi) + jnp.einsum("hu,...uv->...hv", Fvi, Zr)
+    Yr = _ein("hu,...uv->...hv", Fvr, Zr) - _ein("hu,...uv->...hv", Fvi, Zi)
+    Yi = _ein("hu,...uv->...hv", Fvr, Zi) + _ein("hu,...uv->...hv", Fvi, Zr)
     # x = Re( Y @ W^T ) = Yr @ Wr^T - Yi @ Wi^T
-    return jnp.einsum("...hv,wv->...hw", Yr, Wr) - jnp.einsum("...hv,wv->...hw", Yi, Wi)
+    return _ein("...hv,wv->...hw", Yr, Wr) - _ein("...hv,wv->...hw", Yi, Wi)
 
 
 def num_freq(delta: int) -> int:
@@ -146,6 +158,40 @@ def compact_layout(delta: int):
     return jnp.asarray(store), jnp.asarray(src), jnp.asarray(sgn)
 
 
+@functools.lru_cache(maxsize=None)
+def _compact_inverse_np(delta: int):
+    """The compact-layout inverse folded into one matmul pair.
+
+    Returns ``(Kr, Ki)`` float32, each ``(P_real, delta * delta)``, with
+    ``zr @ Kr + zi @ Ki`` equal to the flattened
+    ``irfft2_tiles(*unpack_half_spectrum(zr, zi, delta), delta)``: the
+    conj-mirror scatter and both inverse DFT factors become fixed
+    weights, so a kernel needs no gather.
+    """
+    d = delta
+    dh = d // 2 + 1
+    _, src, sgn = _compact_layout_np(d)
+    u = np.arange(d)
+    finv = np.exp(2j * np.pi * np.outer(u, u) / d) / d              # (h, u)
+    v = np.arange(dh)
+    self_conj = (v == 0) | ((d % 2 == 0) & (v == d // 2))
+    w = (np.exp(2j * np.pi * np.outer(u, v) / d)
+         * np.where(self_conj, 1.0, 2.0)[None, :] / d)              # (w, v)
+    # y[h, w] = Re sum_{u,v} finv[h, u] Z[u, v] w[w, v], one row per (u, v)
+    a = np.einsum("hu,wv->uvhw", finv, w).reshape(d * dh, d * d)
+    P = int(src.max()) + 1
+    kr = np.zeros((P, d * d))
+    ki = np.zeros((P, d * d))
+    np.add.at(kr, src, a.real)
+    np.add.at(ki, src, -sgn[:, None] * a.imag)
+    return kr.astype(np.float32), ki.astype(np.float32)
+
+
+def compact_inverse_mats(delta: int):
+    """jnp copies of the folded compact-layout inverse ``(Kr, Ki)``."""
+    return tuple(jnp.asarray(m) for m in _compact_inverse_np(delta))
+
+
 def pack_half_spectrum(Tr, Ti, delta: int):
     """Rect rfft2 planes (..., delta, delta_h) -> compact (..., P_real)."""
     store, _, _ = compact_layout(delta)
@@ -173,10 +219,10 @@ def fft2_full_tiles(x, delta: int):
     """Batched full fft2 of real tiles: (..., delta, delta) -> two
     (..., delta, delta) planes (the ``spectrum="complex"`` twin)."""
     Fr, Fi, *_ = dft_mats(delta)
-    Ar = jnp.einsum("uh,...hw->...uw", Fr, x)
-    Ai = jnp.einsum("uh,...hw->...uw", Fi, x)
-    Tr = jnp.einsum("...uw,vw->...uv", Ar, Fr) - jnp.einsum("...uw,vw->...uv", Ai, Fi)
-    Ti = jnp.einsum("...uw,vw->...uv", Ar, Fi) + jnp.einsum("...uw,vw->...uv", Ai, Fr)
+    Ar = _ein("uh,...hw->...uw", Fr, x)
+    Ai = _ein("uh,...hw->...uw", Fi, x)
+    Tr = _ein("...uw,vw->...uv", Ar, Fr) - _ein("...uw,vw->...uv", Ai, Fi)
+    Ti = _ein("...uw,vw->...uv", Ar, Fi) + _ein("...uw,vw->...uv", Ai, Fr)
     return Tr, Ti
 
 
@@ -187,6 +233,6 @@ def ifft2_full_tiles(Zr, Zi, delta: int):
     real signals.
     """
     _, _, _, _, Fvr, Fvi, _, _ = dft_mats(delta)
-    Yr = jnp.einsum("hu,...uv->...hv", Fvr, Zr) - jnp.einsum("hu,...uv->...hv", Fvi, Zi)
-    Yi = jnp.einsum("hu,...uv->...hv", Fvr, Zi) + jnp.einsum("hu,...uv->...hv", Fvi, Zr)
-    return jnp.einsum("...hv,wv->...hw", Yr, Fvr) - jnp.einsum("...hv,wv->...hw", Yi, Fvi)
+    Yr = _ein("hu,...uv->...hv", Fvr, Zr) - _ein("hu,...uv->...hv", Fvi, Zi)
+    Yi = _ein("hu,...uv->...hv", Fvr, Zi) + _ein("hu,...uv->...hv", Fvi, Zr)
+    return _ein("...hv,wv->...hw", Yr, Fvr) - _ein("...hv,wv->...hw", Yi, Fvi)

@@ -72,7 +72,9 @@ def conv2d_direct(x, k, *, padding=0, compute_dtype=None):
     the same convention as the FFT path (lax wants (lo, hi) per dim).
     ``compute_dtype`` casts the operands (f32 accumulation, result back in
     ``x.dtype``) — the direct-backend analogue of the FFT schedules' hot
-    CGEMM operand cast.
+    CGEMM operand cast.  Runs at the engine's matmul precision
+    (``repro.core.dft.PRECISION``), so float32 operands stay float32 on a
+    TPU.
     """
     pad = (padding, padding) if isinstance(padding, int) else padding
     out_dtype = x.dtype
@@ -83,7 +85,8 @@ def conv2d_direct(x, k, *, padding=0, compute_dtype=None):
     y = jax.lax.conv_general_dilated(
         x, k, window_strides=(1, 1),
         padding=[(pad[0], pad[0]), (pad[1], pad[1])],
-        dimension_numbers=("NCHW", "OIHW", "NCHW"), **acc,
+        dimension_numbers=("NCHW", "OIHW", "NCHW"),
+        precision=dft.PRECISION, **acc,
     )
     return y.astype(out_dtype) if compute_dtype is not None else y
 

@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import dot_precision
+
 
 def _cgemm_kernel(dr_ref, di_ref, gr_ref, gi_ref, zr_ref, zi_ref,
                   *, three_m: bool):
@@ -35,7 +37,8 @@ def _cgemm_kernel(dr_ref, di_ref, gr_ref, gi_ref, zr_ref, zi_ref,
     di = di_ref[0]
     gr = gr_ref[0]          # (bk, bn)
     gi = gi_ref[0]
-    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
+    dot = functools.partial(jnp.dot, precision=dot_precision(dr.dtype),
+                            preferred_element_type=jnp.float32)
     if three_m:
         t1 = dot(dr, gr)
         t2 = dot(di, gi)

@@ -11,6 +11,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_default
 from repro.kernels.cgemm.kernel import cgemm_pallas_call
 
 
@@ -50,20 +51,31 @@ def default_blocks(M, N, C):
     return _default_blocks(M, N, C)
 
 
-_LANE = 8                                 # sublane-friendly block alignment
+# Mosaic's block rule: the last two dims of every block are a multiple of
+# (8, 128) or span the whole (padded) array dim.  bm is a sublane axis
+# (of D and Z); bn and bk are lane axes (of G and Z, and of D).
+_SUBLANE, _LANE = 8, 128
 
 
-def _shrink_block(dim, block):
-    """Shrink a heuristic default block to fit ``dim`` with at most one
-    lane-alignment's padding, keeping the grid-step count the full-size
-    block would need.  A 100-wide dim under a 128 default becomes 104
-    (one 8-aligned step) instead of zero-padding 28 ghost columns; odd
-    half-spectrum slabs (e.g. P_real=130 rows of M) stop re-padding at
-    every stage that touches them."""
+def _shrink_block(dim, block, align):
+    """Shrink a heuristic default block to fit ``dim``, keeping the
+    grid-step count the full-size block would need.  One step covers the
+    whole dim (8-aligned: a 100-wide dim under a 128 default becomes 104
+    instead of zero-padding 28 ghost columns); several steps stay
+    ``align``-aligned, so odd half-spectrum slabs (e.g. P_real=130 rows of
+    M) stop re-padding at every stage that touches them."""
     steps = max(1, -(-dim // block))
     fitted = -(-dim // steps)             # ceil: balanced across steps
-    fitted = -(-fitted // _LANE) * _LANE  # align up to the lane width
-    return min(block, fitted)
+    step_align = _SUBLANE if steps == 1 else align
+    return min(block, -(-fitted // step_align) * step_align)
+
+
+def _legal_block(dim, block, align):
+    """Round ``block`` up to an edge the TPU accepts: one block spanning
+    the padded ``dim``, or a multiple of ``align``."""
+    if block >= dim:
+        return block
+    return -(-block // align) * align
 
 
 def resolve_blocks(M, N, C, bm=None, bn=None, bk=None, slabs: int = 1):
@@ -71,9 +83,10 @@ def resolve_blocks(M, N, C, bm=None, bn=None, bk=None, slabs: int = 1):
 
     ``None`` means "use the default", shrunk to fit the dim (see
     ``_shrink_block`` — padding is applied once, not per stage); explicit
-    values are honored verbatim and must be positive ints (operands are
-    zero-padded up to block multiples, so any positive edge is legal —
-    the autotuner decides what's *fast*).
+    values must be positive ints.  Every result is legal on a TPU: an
+    explicit edge that is neither a lane/sublane multiple nor covering its
+    dim is rounded up to the next multiple (operands are zero-padded up
+    to block multiples; the autotuner decides what's *fast*).
 
     ``slabs > 1`` resolves for comm/compute-overlapped execution where the
     M axis is subdivided into that many batch sub-slabs: the default bm is
@@ -86,15 +99,16 @@ def resolve_blocks(M, N, C, bm=None, bn=None, bk=None, slabs: int = 1):
         raise ValueError(f"slabs must be a positive int, got {slabs!r}")
     m_fit = max(1, M // slabs)            # smallest sub-slab's row count
     resolved = []
-    for name, v, dim, d in zip(("bm", "bn", "bk"), (bm, bn, bk),
-                               (m_fit, N, C), _default_blocks(m_fit, N, C)):
+    for name, v, dim, d, align in zip(
+            ("bm", "bn", "bk"), (bm, bn, bk), (m_fit, N, C),
+            _default_blocks(m_fit, N, C), (_SUBLANE, _LANE, _LANE)):
         if v is None:
-            v = _shrink_block(dim, d)
+            v = _shrink_block(dim, d, align)
         if isinstance(v, bool) or not isinstance(v, int) or v <= 0:
             raise ValueError(
                 f"cgemm block override {name} must be a positive int or "
                 f"None, got {v!r}")
-        resolved.append(v)
+        resolved.append(_legal_block(dim, v, align))
     return tuple(resolved)
 
 
@@ -104,7 +118,7 @@ def cgemm_pallas(Dr, Di, Gr, Gi, *, bm=None, bn=None, bk=None,
                  three_m: bool = True, interpret: bool | None = None):
     """Batched complex GEMM: (P,M,C) x (P,C,N) -> (P,M,N) (real, imag)."""
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = interpret_default()
     P, M, C = Dr.shape
     N = Gr.shape[-1]
     bm, bn, bk = resolve_blocks(M, N, C, bm, bn, bk)

@@ -7,6 +7,12 @@ Replaces NEON FFT butterflies with MXU matmuls: for each 16x16 tile x,
 A block of ``bt`` tiles is processed per grid step; both matmul stages happen
 in VMEM, so the intermediate (F @ x) never touches HBM — that is the fusion
 the kernel buys over the unfused einsum path.
+
+The compact-spectrum inverse with the fused epilogue (the one kernel here
+on a plan path) instead folds the conj-mirror scatter and both inverse DFT
+factors into two fixed ``(P, delta*delta)`` matrices
+(``repro.core.dft.compact_inverse_mats``): one lane-dense matmul pair per
+block, no in-kernel gather.
 """
 from __future__ import annotations
 
@@ -15,6 +21,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import dot_precision
 
 
 def _fwd_kernel(x_ref, fr_ref, fi_ref, fhr_ref, fhi_ref, tr_ref, ti_ref):
@@ -85,18 +93,15 @@ def _rinv_kernel(zr_ref, zi_ref, fvr_ref, fvi_ref, wr_ref, wi_ref,
     y_ref[...] = y.astype(y_ref.dtype)
 
 
-def _rinv_epilogue_kernel(zr_ref, zi_ref, fvr_ref, fvi_ref, wr_ref, wi_ref,
-                          src_ref, sgn_ref, b_ref, y_ref, *, delta,
+def _rinv_epilogue_kernel(zr_ref, zi_ref, kr_ref, ki_ref, b_ref, y_ref, *,
                           activation):
-    """Compact-layout scatter + inverse tile DFT + bias/activation tail,
-    all on the VMEM-resident block (the ``spectrum="real"`` stage-4 fast
-    path)."""
-    zr, zi = _scatter_to_rect(zr_ref[...], zi_ref[...], src_ref[...][0],
-                              sgn_ref[...][0], delta)
-    y = _inverse_block(zr, zi, fvr_ref[...], fvi_ref[...],
-                       wr_ref[...], wi_ref[...])
-    y = y + b_ref[...][:, :, None]
-    y = _TAIL_ACTIVATIONS[activation](y)
+    """Compact-layout inverse tile DFT + bias/activation tail on the
+    VMEM-resident block (the ``spectrum="real"`` stage-4 fast path):
+    (bt, P) planes @ the folded (P, delta*delta) inverse -> flat tiles."""
+    dot = functools.partial(jnp.dot, precision=dot_precision(zr_ref.dtype),
+                            preferred_element_type=jnp.float32)
+    y = dot(zr_ref[...], kr_ref[...]) + dot(zi_ref[...], ki_ref[...])
+    y = _TAIL_ACTIVATIONS[activation](y + b_ref[...])
     y_ref[...] = y.astype(y_ref.dtype)
 
 
@@ -240,26 +245,23 @@ def tile_irfft_epilogue_call(n: int, delta: int, P: int, dtype, *, bt: int,
                              activation: str = "none",
                              interpret: bool = False):
     """Compact-layout inverse tile DFT with the fused bias+activation tail:
-    2x (n, P) planes + (n, 1) bias -> (n, delta, delta) real."""
+    2x (n, P) planes + 2x (P, delta*delta) folded inverse + (n, 1) bias
+    -> (n, delta*delta) real, one flattened tile per row."""
     assert n % bt == 0
     if activation not in _TAIL_ACTIVATIONS:
         raise ValueError(f"unsupported kernel-tail activation "
                          f"{activation!r}: {tuple(_TAIL_ACTIVATIONS)}")
-    dh = delta // 2 + 1
+    dd = delta * delta
     z_spec = pl.BlockSpec((bt, P), lambda i: (i, 0))
-    y_spec = pl.BlockSpec((bt, delta, delta), lambda i: (i, 0, 0))
+    y_spec = pl.BlockSpec((bt, dd), lambda i: (i, 0))
     b_spec = pl.BlockSpec((bt, 1), lambda i: (i, 0))
-    rect = delta * dh
     return pl.pallas_call(
-        functools.partial(_rinv_epilogue_kernel, delta=delta,
-                          activation=activation),
+        functools.partial(_rinv_epilogue_kernel, activation=activation),
         grid=(n // bt,),
-        in_specs=[z_spec, z_spec, _mat_spec((delta, delta)),
-                  _mat_spec((delta, delta)), _mat_spec((delta, dh)),
-                  _mat_spec((delta, dh)), _mat_spec((1, rect)),
-                  _mat_spec((1, rect)), b_spec],
+        in_specs=[z_spec, z_spec, _mat_spec((P, dd)), _mat_spec((P, dd)),
+                  b_spec],
         out_specs=y_spec,
-        out_shape=jax.ShapeDtypeStruct((n, delta, delta), dtype),
+        out_shape=jax.ShapeDtypeStruct((n, dd), dtype),
         interpret=interpret,
     )
 

@@ -8,7 +8,10 @@ import jax.numpy as jnp
 
 import math
 
-from repro.core.dft import dft_mats, compact_layout, num_freq_real
+from repro.core.dft import (
+    compact_inverse_mats, compact_layout, dft_mats, num_freq_real,
+)
+from repro.kernels import interpret_default
 from repro.kernels.dft_tile.kernel import (
     tile_fft_call, tile_ifft_call, tile_ifft_epilogue_call,
     tile_rfft_call, tile_irfft_call, tile_irfft_epilogue_call,
@@ -16,6 +19,7 @@ from repro.kernels.dft_tile.kernel import (
 
 
 DEFAULT_BT = 256                        # tile-batch block (grid rows/step)
+_SUBLANE = 8                            # bt is the sublane axis of a block
 
 
 def _pad_tiles(x, bt):
@@ -30,13 +34,14 @@ def resolve_bt(n: int, bt=None, slabs: int = 1) -> int:
     """Merge an explicit tile-batch block override over ``DEFAULT_BT``.
 
     ``None`` means "use the default"; explicit values must be positive
-    ints and are honored verbatim (clamped to the tile count — padding a
-    6-tile problem to a 256-wide block would be pure waste).  The default
-    additionally *shrinks to fit*: it keeps the grid-step count the
-    full-size default would need and balances the block across those
-    steps, so padding is applied at most once for the whole batch instead
-    of up to ``bt - 1`` ghost tiles per call (n=1000 gets bt=250, not a
-    256-block padded to 1024).
+    ints (clamped to the tile count — padding a 6-tile problem to a
+    256-wide block would be pure waste).  The default additionally
+    *shrinks to fit*: it keeps the grid-step count the full-size default
+    would need and balances the block across those steps, so padding is
+    applied at most once for the whole batch instead of up to ``bt - 1``
+    ghost tiles per call.  Every result is legal on a TPU: one block
+    spanning all tiles, or a multiple of 8 (n=1000 gets bt=256 in four
+    steps; an explicit bt=12 becomes 16).
 
     ``slabs > 1`` resolves for overlapped (sub-slab) execution: ``n`` is
     the un-slabbed tile count and the block is fitted to the *smallest*
@@ -48,12 +53,14 @@ def resolve_bt(n: int, bt=None, slabs: int = 1) -> int:
     n_fit = max(1, n // slabs)
     if bt is None:
         steps = max(1, math.ceil(n_fit / DEFAULT_BT))
-        return max(1, math.ceil(n_fit / steps))
-    if isinstance(bt, bool) or not isinstance(bt, int) or bt <= 0:
+        bt = max(1, math.ceil(n_fit / steps))
+    elif isinstance(bt, bool) or not isinstance(bt, int) or bt <= 0:
         raise ValueError(
             f"dft_tile block override bt must be a positive int or None, "
             f"got {bt!r}")
-    return min(bt, max(n_fit, 1))
+    if bt >= n_fit:
+        return n_fit                      # one block spans every tile
+    return -(-bt // _SUBLANE) * _SUBLANE
 
 
 @functools.partial(jax.jit, static_argnames=("delta", "bt", "interpret"))
@@ -61,7 +68,7 @@ def tile_fft_pallas(x, *, delta: int = 16, bt: int | None = None,
                     interpret: bool | None = None):
     """Forward DFT of tiles: (n, delta, delta) -> 2x (n, delta, dh)."""
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = interpret_default()
     n = x.shape[0]
     bt = resolve_bt(n, bt)
     xp = _pad_tiles(x, bt)
@@ -77,7 +84,7 @@ def tile_ifft_pallas(Zr, Zi, *, delta: int = 16, bt: int | None = None,
                      interpret: bool | None = None):
     """Inverse DFT of tiles: 2x (n, delta, dh) -> (n, delta, delta)."""
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = interpret_default()
     n = Zr.shape[0]
     bt = resolve_bt(n, bt)
     Zrp, Zip = _pad_tiles(Zr, bt), _pad_tiles(Zi, bt)
@@ -99,7 +106,7 @@ def tile_ifft_epilogue_pallas(Zr, Zi, bias, *, activation: str = "none",
     still VMEM-resident: 2x (n, delta, dh) + (n,) -> (n, delta, delta).
     """
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = interpret_default()
     n = Zr.shape[0]
     bt = resolve_bt(n, bt)
     Zrp, Zip = _pad_tiles(Zr, bt), _pad_tiles(Zi, bt)
@@ -129,7 +136,7 @@ def tile_rfft_pallas(x, *, delta: int = 16, bt: int | None = None,
     ``repro.core.dft.compact_layout``).  DC/Nyquist self-conjugate columns
     keep only their non-redundant rows, for even and odd delta alike."""
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = interpret_default()
     n = x.shape[0]
     bt = resolve_bt(n, bt)
     xp = _pad_tiles(x, bt)
@@ -148,7 +155,7 @@ def tile_irfft_pallas(Zr, Zi, *, delta: int = 16, bt: int | None = None,
     """Compact-layout inverse DFT: 2x (n, P) -> (n, delta, delta) real.
     Accepts ``P >= num_freq_real(delta)`` (trailing padding is ignored)."""
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = interpret_default()
     n, P = Zr.shape
     bt = resolve_bt(n, bt)
     Zrp, Zip = _pad_tiles(Zr, bt), _pad_tiles(Zi, bt)
@@ -168,14 +175,16 @@ def tile_irfft_epilogue_pallas(Zr, Zi, bias, *, activation: str = "none",
     tail: 2x (n, P) + (n,) bias -> (n, delta, delta), bias-shifted and
     activated while the block is VMEM-resident."""
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = interpret_default()
     n, P = Zr.shape
     bt = resolve_bt(n, bt)
     Zrp, Zip = _pad_tiles(Zr, bt), _pad_tiles(Zi, bt)
     bp = _pad_tiles(bias.reshape(n, 1).astype(Zr.dtype), bt)
-    *_, Fvr, Fvi, Wr, Wi = dft_mats(delta)
-    _, src, sgn = _layout_operands(delta)
+    # rows past the layout's point count (all-to-all padding) get zero
+    # weights, so the padded frequencies drop out of the matmul
+    Kr, Ki = (jnp.pad(m, ((0, P - m.shape[0]), (0, 0)))
+              for m in compact_inverse_mats(delta))
     call = tile_irfft_epilogue_call(Zrp.shape[0], delta, P, Zr.dtype, bt=bt,
                                     activation=activation,
                                     interpret=interpret)
-    return call(Zrp, Zip, Fvr, Fvi, Wr, Wi, src, sgn, bp)[:n]
+    return call(Zrp, Zip, Kr, Ki, bp)[:n].reshape(n, delta, delta)

@@ -39,6 +39,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import itertools
 import time
 import warnings
@@ -296,12 +297,13 @@ class ServeEngine:
         net = plan_network(self._layers_for(key), **self._plan_kwargs)
         self.nets[key] = net
         self._bucket_x[key] = net[net.layer_names[0]].x_shape
-        fwd = self._forward
+        # the prepared network is a jit argument: its transformed kernels
+        # are executable inputs, not constants compiled into each bucket
+        fwd = jax.jit(self._forward)
         for r in range(self.replicas):
             prepared = net.prepare(
                 self._params[r], weights_version=self.weights_version)
-            self._exec[r][key] = jax.jit(
-                lambda x, _p=prepared: fwd(_p, x))
+            self._exec[r][key] = functools.partial(fwd, prepared)
 
     def _load_buckets(self, path: str, keys) -> None:
         """Rehydrate every bucket executor from an AOT plan artifact —

@@ -35,6 +35,10 @@ cache per-cell JSON for the roofline table (EXPERIMENTS.md §Dry-run).
     PYTHONPATH=src python -m repro.launch.dryrun --all [--multi-pod]
 """
 
+# The chip the emulated production mesh stands for (a ``roofline.PEAKS``
+# key): the host devices that compile here have no peaks of their own.
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "experiments", "dryrun")
 
@@ -168,7 +172,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
         rec["analytic_flops"] = ac["flops"]
         rec["analytic_bytes"] = ac["bytes"]
         terms = roofline_terms(ac["flops"] / n_dev, ac["bytes"] / n_dev,
-                               coll["total_bytes"])
+                               coll["total_bytes"],
+                               device_kind=TARGET_DEVICE_KIND)
         rec["roofline"] = terms
         if cfg.encdec:
             enc_p, dec_p = cfg.encdec_split()

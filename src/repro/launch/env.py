@@ -1,4 +1,11 @@
-"""Emulated-NUMA process environment for the overlapped conv schedules.
+"""Process environment: the compile cache, and the emulated-NUMA mesh for
+the overlapped conv schedules.
+
+``compile_cache_dir()`` places JAX's persistent compilation cache.  An
+entry point calls it first thing, so every process of a run (children
+included) reuses the programs earlier runs compiled.
+
+The rest of this module emulates the paper's NUMA mesh on a CPU host.
 
 The paper's target is a many-core ARMv8 CPU whose NUMA nodes each own a
 slice of the batch/channel axes; this repo emulates that mesh on one host
@@ -43,6 +50,31 @@ import argparse
 import os
 import sys
 from typing import Optional, Tuple
+
+# <checkout>/.jax_cache: a fixed path, because the path is part of the
+# cache's key — a directory that moves between runs never hits.
+_DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def compile_cache_dir() -> str:
+    """Place JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, wins and nothing is changed.
+    Otherwise the cache goes to ``<checkout>/.jax_cache``: exported in the
+    environment (child processes inherit it, and jax reads it when it is
+    imported) and, if jax is already imported, set in its config.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    path = _DEFAULT_CACHE_DIR
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = path
+    if "jax" in sys.modules:
+        sys.modules["jax"].config.update("jax_compilation_cache_dir", path)
+    return path
+
 
 _OVERLAP_FLAGS = (
     "--xla_cpu_use_thunk_runtime=true",

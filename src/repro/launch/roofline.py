@@ -1,14 +1,13 @@
 """Roofline-term extraction from compiled XLA artifacts.
 
-Hardware model (TPU v5e, per §ROOFLINE):
-    peak bf16 compute   197 TFLOP/s per chip
-    HBM bandwidth       819 GB/s per chip
-    ICI link bandwidth  ~50 GB/s per link
+Hardware model: per-chip peaks from ``PEAKS``, keyed by
+``jax.Device.device_kind``.  A device kind that is not in the table is an
+error, never a default.
 
 Terms (seconds):
-    compute    = HLO_FLOPs  / (chips * PEAK_FLOPS)
-    memory     = HLO_bytes  / (chips * HBM_BW)
-    collective = coll_bytes / (chips * LINK_BW)
+    compute    = HLO_FLOPs  / (chips * flops)
+    memory     = HLO_bytes  / (chips * hbm_bw)
+    collective = coll_bytes / (chips * link_bw)
 
 ``cost_analysis()`` of a GSPMD-partitioned executable describes the
 *per-device* program, so per-device values are multiplied by the chip count
@@ -22,11 +21,27 @@ counted once; ``-done`` skipped).
 """
 from __future__ import annotations
 
+import collections
 import re
 
-PEAK_FLOPS = 197e12        # bf16 FLOP/s per chip
-HBM_BW = 819e9             # B/s per chip
-LINK_BW = 50e9             # B/s per link
+Peaks = collections.namedtuple("Peaks", ["flops", "hbm_bw", "link_bw"])
+
+# Per-chip peaks by device kind.  TPU v5e ("TPU v5 lite"): Google Cloud
+# documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+# 1,600 Gbit/s of chip-to-chip interconnect over 4 links (50 GB/s each).
+PEAKS = {
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, link_bw=50e9),
+}
+
+
+def peaks(device_kind: str) -> Peaks:
+    """The ``PEAKS`` row for ``device_kind`` (a ``Device.device_kind``)."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peak figures for device kind {device_kind!r} "
+                       f"(known: {sorted(PEAKS)})") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -156,10 +171,11 @@ def parse_collectives(hlo_text: str) -> dict:
 
 
 def roofline_terms(flops_per_dev: float, bytes_per_dev: float,
-                   coll_bytes_per_dev: float) -> dict:
-    comp = flops_per_dev / PEAK_FLOPS
-    mem = bytes_per_dev / HBM_BW
-    coll = coll_bytes_per_dev / LINK_BW
+                   coll_bytes_per_dev: float, *, device_kind: str) -> dict:
+    pk = peaks(device_kind)
+    comp = flops_per_dev / pk.flops
+    mem = bytes_per_dev / pk.hbm_bw
+    coll = coll_bytes_per_dev / pk.link_bw
     dom = max(("compute", comp), ("memory", mem), ("collective", coll),
               key=lambda kv: kv[1])
     total = max(comp, mem, coll)

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCH_NAMES, get_config
+from repro.launch.env import compile_cache_dir
 from repro.models import lm as LM
 from repro.models import whisper as WH
 from repro.train import make_prefill_step, make_decode_step
@@ -33,7 +34,7 @@ from repro.train import make_prefill_step, make_decode_step
 
 # Table-I VGG entries chain into a sequential trunk with a 2x2 max-pool
 # after each of these layers (the Table geometries already reflect it).
-_VGG_POOL_AFTER = frozenset(
+VGG_POOL_AFTER = frozenset(
     {"Vconv1.2", "Vconv2.2", "Vconv3.2", "Vconv4.2", "Vconv5"})
 
 
@@ -47,7 +48,7 @@ def _vgg_scale(image):
             for l in TABLE1 if l.name.startswith("V")]
 
 
-def _vgg_forward(biases):
+def vgg_forward(biases):
     """Prepared-network forward for the VGG trunk: chained prepared
     layers with fused bias+ReLU epilogues, 2x2 max-pool after each
     block (closure-held biases are batch-independent, so one callable
@@ -56,7 +57,7 @@ def _vgg_forward(biases):
         from repro.models.layers import maxpool2x2
         for name in prepared:
             x = prepared[name](x, bias=biases[name])
-            if name in _VGG_POOL_AFTER:
+            if name in VGG_POOL_AFTER:
                 x = maxpool2x2(x)
         return x
     return forward
@@ -113,7 +114,7 @@ def serve_convnet(args):
     kernels = {n: init(net[n].k_shape) for n in net}
     biases = {n: init((net[n].spec.Cout,)) for n in net}
 
-    forward = _vgg_forward(biases)
+    forward = vgg_forward(biases)
 
     t0 = time.time()
     prepared = net.prepare(kernels, weights_version=0)
@@ -186,7 +187,7 @@ def serve_trace(args):
     probe = make_layers(1)
     kernels = {l.name: init(l.k_shape) for l in probe}
     biases = {l.name: init((l.k_shape[0],)) for l in probe}
-    forward = _vgg_forward(biases)
+    forward = vgg_forward(biases)
     backend = "tuned" if args.tune else args.conv_backend
 
     policy = BucketPolicy(max_batch=args.max_batch)
@@ -419,6 +420,7 @@ def main(argv=None):
     ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache_dir()
 
     if (args.tune or args.serve_trace) and not args.convnet:
         args.convnet = "vgg"        # conv-only flags imply the convnet path

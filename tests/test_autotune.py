@@ -117,6 +117,28 @@ def test_spec_signature_separates_geometry_and_constraints(tune_env):
                                          spectrum="complex")
 
 
+def test_failing_candidate_is_reported_not_skipped(tune_env, monkeypatch):
+    """A candidate that fails to compile or run fails the sweep with the
+    candidate and its error — the tuner never silently measures around a
+    broken kernel."""
+    real = autotune._measure_candidate
+
+    def flaky(cand, *args, **kwargs):
+        if cand.backend == "fft-pallas":
+            raise ValueError("Mosaic refused the block shape")
+        return real(cand, *args, **kwargs)
+
+    monkeypatch.setattr(autotune, "_measure_candidate", flaky)
+    with pytest.raises(RuntimeError, match="fft-pallas.*Mosaic refused"):
+        autotune.tune(X_SHAPE, K_SHAPE, padding=1, budget=1e9)
+    assert not os.path.exists(tune_env)     # nothing persisted
+
+
+def test_device_kind_is_the_real_device(tune_env):
+    assert autotune._device_kind() == \
+        str(jax.devices()[0].device_kind).replace("|", "/")
+
+
 def test_corrupt_cache_file_is_tolerated(tune_env):
     tune_env.write_text("{not json!!")
     w = autotune.tune(X_SHAPE, K_SHAPE, padding=1)     # re-measures
@@ -252,7 +274,7 @@ def test_resolve_blocks_defaults_and_validation():
     assert default_blocks(100, 24, 3) == (128, 32, 8)
     assert resolve_blocks(100, 24, 3) == (104, 24, 8)
     assert resolve_blocks(128, 32, 8) == (128, 32, 8)  # exact fit: verbatim
-    # explicit pins are honored verbatim; unpinned dims still shrink
+    # legal explicit pins are honored verbatim; unpinned dims still shrink
     assert resolve_blocks(100, 24, 3, bm=16, bk=64) == (16, 24, 64)
     for bad in (0, -8, 2.5, True, "16"):
         with pytest.raises(ValueError, match="positive int"):
@@ -261,8 +283,9 @@ def test_resolve_blocks_defaults_and_validation():
 
 def test_resolve_bt_defaults_clamp_and_validation():
     from repro.kernels.dft_tile import DEFAULT_BT, resolve_bt
-    # default shrinks to fit: same step count as DEFAULT_BT, balanced
-    assert resolve_bt(1000) == 250
+    # default shrinks to fit: same step count as DEFAULT_BT, balanced,
+    # and sublane-aligned so the block stays TPU-legal (4 x 256 >= 1000)
+    assert resolve_bt(1000) == 256
     assert resolve_bt(DEFAULT_BT) == DEFAULT_BT
     assert resolve_bt(10) == 10                # smaller than the default
     assert resolve_bt(1000, 64) == 64          # explicit pin: verbatim

@@ -191,6 +191,39 @@ def test_parse_collectives_counts_loop_trips():
 
 
 def test_roofline_terms_dominance():
-    t = roofline_terms(197e12 * 2, 819e9, 50e9 * 3)
+    t = roofline_terms(197e12 * 2, 819e9, 50e9 * 3,
+                       device_kind="TPU v5 lite")
     assert t["dominant"] == "collective"
     assert t["bound_s"] == pytest.approx(3.0)
+    with pytest.raises(KeyError, match="no peak figures"):
+        roofline_terms(1.0, 1.0, 1.0, device_kind="cpu")
+
+
+# --------------------------------------------------------------------------
+# compile cache placement
+# --------------------------------------------------------------------------
+
+def test_compile_cache_dir_from_environment_wins(monkeypatch, tmp_path):
+    from repro.launch.env import compile_cache_dir
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache_dir() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before  # nothing set
+
+
+def test_compile_cache_dir_defaults_to_the_checkout(monkeypatch):
+    from repro.launch.env import compile_cache_dir
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(checkout, ".jax_cache")
+    before = jax.config.jax_compilation_cache_dir
+    # set, then delete: monkeypatch then removes the variable the helper
+    # exports when the test ends
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        assert compile_cache_dir() == want
+        assert os.environ["JAX_COMPILATION_CACHE_DIR"] == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert compile_cache_dir() == want          # a fixed path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
