@@ -93,8 +93,6 @@ def main(argv=None) -> dict:
         sys.stdout.flush()
         _spectrum_rows(quick=args.quick)
         sys.stdout.flush()
-        _conv_roofline_rows()
-        sys.stdout.flush()
     finally:
         sys.stdout = tee.wrapped
 
@@ -424,26 +422,6 @@ def _serve_rows(quick: bool = True) -> dict:
         metric = name.rsplit("/", 1)[1]
         print(f"{name},{rows[name]['us_per_call']:.1f},{metric}")
     return rows
-
-
-def _conv_roofline_rows():
-    """§Perf conv hillclimb rows (from the saved production-mesh analysis;
-    regenerate with `python -m benchmarks.conv_roofline`)."""
-    import os
-    path = os.path.join(os.path.dirname(__file__), "..", "experiments",
-                        "conv_roofline_vconv42.json")
-    if not os.path.exists(path):
-        print("# conv_roofline: no cached analysis; run "
-              "`python -m benchmarks.conv_roofline`")
-        return
-    print("# conv_roofline Vconv4.2 (cached 16x16-mesh analysis; wall on "
-          "8-dev host) — name,us_per_call,derived(coll bytes/dev)")
-    with open(path) as fh:
-        res = json.load(fh)
-    for v, r in res.items():
-        wall = r.get("wall", {}).get("wall_s", 0.0)
-        print(f"conv_roofline/Vconv4.2/{v},{wall*1e6:.0f},"
-              f"{r['analysis']['coll_bytes_dev']:.3e}")
 
 
 if __name__ == "__main__":

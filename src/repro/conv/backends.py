@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import functools
 
+import jax
 import jax.numpy as jnp
 
 from repro.conv import stages
@@ -92,11 +93,14 @@ def _pallas_fused_inverse_real(Zr, Zi, spec, epilogue, bias, *, bt=None):
 
 
 def _exec_direct(plan, x, k, bias=None, residual=None):
-    y = F.conv2d_direct(x, k, padding=plan.padding,
-                        compute_dtype=plan.compute_dtype)
-    out_dtype = y.dtype
-    return apply_epilogue(y, plan.epilogue, bias=bias,
-                          residual=residual).astype(out_dtype)
+    # named like the fft stage ops (``repro.conv.stages._stage``), so a
+    # profiler trace attributes the conv and its epilogue to ``direct``
+    with jax.named_scope("direct"):
+        y = F.conv2d_direct(x, k, padding=plan.padding,
+                            compute_dtype=plan.compute_dtype)
+        out_dtype = y.dtype
+        return apply_epilogue(y, plan.epilogue, bias=bias,
+                              residual=residual).astype(out_dtype)
 
 
 def _fft_xla_pipeline(plan):

@@ -39,6 +39,13 @@ half-spectrum (~0.51x the frequency points) through the whole graph —
 the nfft boundary all-to-alls and the wfft hot psum pair move roughly
 half the bytes of the ``"complex"`` full-spectrum twin.
 
+Each forward stage op (1, 3 and 4) also opens a ``jax.named_scope`` of
+its counter's name — ``input_transform``, ``cgemm``, ``output_inverse``
+— so its device ops carry ``<caller scope>/<stage>/…`` in the compiled
+HLO's ``op_name`` metadata, where a profiler trace can attribute them.
+Stage 2 runs only in ``prepare`` and the collectives sit between stages:
+neither is scoped.
+
 Stage-op invocations are counted at trace time via the thread-safe
 context manager::
 
@@ -109,9 +116,19 @@ def stage_trace():
 # Stage ops (counted)
 # --------------------------------------------------------------------------
 
+@contextlib.contextmanager
+def _stage(name: str):
+    """Count a stage op and name its device ops ``<caller scope>/<name>/…``
+    in the compiled HLO's ``op_name`` metadata (trace-time only: the
+    compiled program is otherwise the same)."""
+    _count(name)
+    with jax.named_scope(name):
+        yield
+
+
 def stage_input_transform(x, spec: ConvSpec, spectrum: str = "rect"):
-    _count("input_transform")
-    return F.input_transform(x, spec, spectrum=spectrum)
+    with _stage("input_transform"):
+        return F.input_transform(x, spec, spectrum=spectrum)
 
 
 def stage_kernel_transform(k, spec: ConvSpec, spectrum: str = "rect"):
@@ -120,7 +137,6 @@ def stage_kernel_transform(k, spec: ConvSpec, spectrum: str = "rect"):
 
 
 def stage_cgemm(Dr, Di, Gr, Gi, *, three_m: bool, cgemm_fn=None):
-    _count("cgemm")
     # dtype-flow fact for the analyzer: which dtype the hot stage actually
     # consumed (tuple keys ride the same counters as the op counts)
     _count(("cgemm_dtype", str(jnp.result_type(Dr, Gr))))
@@ -131,7 +147,8 @@ def stage_cgemm(Dr, Di, Gr, Gi, *, three_m: bool, cgemm_fn=None):
             (int(Dr.shape[-2]), int(Gr.shape[-1]), int(Dr.shape[-1]))))
     mm = cgemm_fn if cgemm_fn is not None else functools.partial(
         cgemm, three_m=three_m)
-    return mm(Dr, Di, Gr, Gi)
+    with _stage("cgemm"):
+        return mm(Dr, Di, Gr, Gi)
 
 
 def stage_output_inverse(Zr, Zi, spec: ConvSpec, *, epilogue: Epilogue = None,
@@ -146,12 +163,12 @@ def stage_output_inverse(Zr, Zi, spec: ConvSpec, *, epilogue: Epilogue = None,
     cannot fold a residual — the residual lives in output layout, not tile
     layout — so residual epilogues fall back to the composed path.
     """
-    _count("output_inverse")
-    if (inverse_fn is not None and epilogue is not None
-            and not epilogue.is_noop and not epilogue.residual):
-        return inverse_fn(Zr, Zi, spec, epilogue, bias)
-    y = F.output_inverse(Zr, Zi, spec, spectrum=spectrum)
-    return apply_epilogue(y, epilogue, bias=bias, residual=residual)
+    with _stage("output_inverse"):
+        if (inverse_fn is not None and epilogue is not None
+                and not epilogue.is_noop and not epilogue.residual):
+            return inverse_fn(Zr, Zi, spec, epilogue, bias)
+        y = F.output_inverse(Zr, Zi, spec, spectrum=spectrum)
+        return apply_epilogue(y, epilogue, bias=bias, residual=residual)
 
 
 def _boundary_a2a(Tr, Ti, axis_name, split, concat):
