@@ -214,6 +214,7 @@ class PlanProfile:
     blocks: Optional[Tuple] = None             # plan (bm, bn, bk) pins
     cgemm_shapes: Tuple = ()                   # distinct (M, N, K) at stage 3
     overlap_delta: Optional[Dict[str, Any]] = None   # vs sequential twin
+    transform_forms: Tuple[str, ...] = ()      # distinct tile-DFT forms traced
 
     def describe_key(self) -> str:
         tags = [self.backend, self.schedule]
@@ -245,6 +246,7 @@ class PlanProfile:
         d["cgemm_dtypes"] = list(self.cgemm_dtypes)
         d["blocks"] = list(self.blocks) if self.blocks else None
         d["cgemm_shapes"] = [list(s) for s in self.cgemm_shapes]
+        d["transform_forms"] = list(self.transform_forms)
         return d
 
 
@@ -394,6 +396,16 @@ def _rule_rfft_halves_collective_bytes(p: PlanProfile) -> Optional[str]:
     return None
 
 
+def _rule_real_spectrum_folded(p: PlanProfile) -> Optional[str]:
+    if not p.is_pipeline or p.spectrum != "real":
+        return None
+    if set(p.transform_forms) != {"folded"}:
+        return (f"real-spectrum stages 1/4 traced tile-DFT forms "
+                f"{sorted(set(p.transform_forms))}; expected only "
+                f"'folded' (one matmul per tile)")
+    return None
+
+
 def _rule_prepared_elides_boundary(p: PlanProfile) -> Optional[str]:
     if not (p.prepared and p.elision):
         return None
@@ -488,6 +500,10 @@ def _register_builtin_invariants() -> None:
         _rule_rfft_halves_collective_bytes,
         "the compact half-spectrum wfft plan moves <= 0.55x the hot psum "
         "bytes of its full-spectrum (complex) twin")
+    register_invariant(
+        "*", "*", "real-spectrum-folded", _rule_real_spectrum_folded,
+        "every spectrum='real' pipeline applies the tile DFT of stages 1 "
+        "and 4 as one folded matmul per tile")
     register_invariant(
         "*", "*", "stage-ops-once", _rule_stage_ops_once,
         "each pipeline stage op traces exactly once (stage 2 zero times "
@@ -637,6 +653,9 @@ def _profile_from_trace(plan, jaxpr, counts, *, prepared: bool):
     cgemm_shapes = tuple(sorted(
         k[1] for k in counts if isinstance(k, tuple) and k[0] == "cgemm_shape"
     ))
+    transform_forms = tuple(sorted(
+        k[1] for k in counts
+        if isinstance(k, tuple) and k[0] == "transform_form"))
     be = registry.get_backend(plan.backend)
     return PlanProfile(
         backend=plan.backend, schedule=plan.schedule, prepared=prepared,
@@ -651,7 +670,8 @@ def _profile_from_trace(plan, jaxpr, counts, *, prepared: bool):
         spectrum=getattr(plan, "spectrum", "real"),
         overlap=getattr(plan, "overlap", "off"),
         num_slabs=getattr(plan, "num_slabs", 1),
-        blocks=(plan.bm, plan.bn, plan.bk), cgemm_shapes=cgemm_shapes)
+        blocks=(plan.bm, plan.bn, plan.bk), cgemm_shapes=cgemm_shapes,
+        transform_forms=transform_forms)
 
 
 def analyze(target, *, prepared: bool = False) -> PlanProfile:
@@ -772,10 +792,11 @@ def seeded_violation(mode: str = "extra-collective"):
       extra-stage       the kernel transform runs twice per trace;
       skip-cast         compute_dtype casts silently dropped (collectives
                         move full-width bytes again);
-      rfft-unpacked     the compact-Hermitian pack degrades to a plain
-                        half-plane flatten — real-spectrum plans ship the
-                        redundant self-conjugate rows again and the
-                        bytes-ratio invariants must trip;
+      rfft-unpacked     the folded forward keeps the whole rect
+                        half-plane (delta x (delta//2+1) points) —
+                        real-spectrum plans ship the redundant
+                        self-conjugate rows again and the bytes-ratio
+                        invariants must trip;
       overlap-oversend  every sub-slab collective pads its M rows 2x
                         before the wire and slices back after — only
                         overlapped plans are hit (the sequential twin is
@@ -835,22 +856,22 @@ def seeded_violation(mode: str = "extra-collective"):
         finally:
             stages.stage_kernel_transform = orig
     elif mode == "rfft-unpacked":
-        from repro.core import fftconv
+        import jax.numpy as jnp
+        from repro.core import dft
 
-        orig = fftconv.pack_half_spectrum
+        orig = dft.compact_forward_mat
 
-        def broken(Tr, Ti, delta):
-            # keep the full half-plane (delta x (delta//2+1)) flattened:
-            # shape-consistent downstream (unpack reads a prefix) but the
-            # redundant conjugate rows ride every collective again
-            return (Tr.reshape(*Tr.shape[:-2], -1),
-                    Ti.reshape(*Ti.shape[:-2], -1))
+        def broken(delta):
+            # the rect half-plane's points, redundant conjugate rows and
+            # all: shape-consistent downstream (the folded inverse reads a
+            # prefix) but those rows ride every collective again
+            return jnp.asarray(dft._folded_forward_np(delta, compact=False))
 
-        fftconv.pack_half_spectrum = broken
+        dft.compact_forward_mat = broken
         try:
             yield
         finally:
-            fftconv.pack_half_spectrum = orig
+            dft.compact_forward_mat = orig
     elif mode == "skip-cast":
         orig = stages._maybe_cast
 

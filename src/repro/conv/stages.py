@@ -55,7 +55,9 @@ context manager::
 
 Traces also record dtype facts as ``("cgemm_dtype", <dtype>)`` tuple keys
 alongside the plain string op counts — the static analyzer reads these to
-certify that ``compute_dtype`` actually reached the hot stage.
+certify that ``compute_dtype`` actually reached the hot stage — and, from
+stages 1 and 4, ``("transform_form", "folded" | "separable")``: how the
+tile DFT was applied (``repro.core.fftconv.transform_form``).
 """
 from __future__ import annotations
 
@@ -127,6 +129,7 @@ def _stage(name: str):
 
 
 def stage_input_transform(x, spec: ConvSpec, spectrum: str = "rect"):
+    _count(("transform_form", F.transform_form(spectrum)))
     with _stage("input_transform"):
         return F.input_transform(x, spec, spectrum=spectrum)
 
@@ -163,6 +166,7 @@ def stage_output_inverse(Zr, Zi, spec: ConvSpec, *, epilogue: Epilogue = None,
     cannot fold a residual — the residual lives in output layout, not tile
     layout — so residual epilogues fall back to the composed path.
     """
+    _count(("transform_form", F.transform_form(spectrum)))
     with _stage("output_inverse"):
         if (inverse_fn is not None and epilogue is not None
                 and not epilogue.is_noop and not epilogue.residual):

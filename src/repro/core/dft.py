@@ -11,6 +11,11 @@ precomputed DFT matrices:
 where delta_h = delta//2 + 1 and Wr folds the Hermitian-redundant columns
 back with weight 2 (columns 0 and Nyquist with weight 1).
 
+For the compact Hermitian layout both factors fold further, into ONE
+matrix per direction (``compact_forward_mat``, ``compact_inverse_mats``,
+``cropped_inverse_mats``): a tile's delta^2 pixels contract against it
+in a single matmul, so no separable intermediate is written.
+
 All complex arithmetic is struct-of-arrays (separate real/imag planes);
 neither the MXU nor Pallas has a native complex dtype.
 
@@ -190,6 +195,50 @@ def _compact_inverse_np(delta: int):
 def compact_inverse_mats(delta: int):
     """jnp copies of the folded compact-layout inverse ``(Kr, Ki)``."""
     return tuple(jnp.asarray(m) for m in _compact_inverse_np(delta))
+
+
+@functools.lru_cache(maxsize=None)
+def _cropped_inverse_np(delta: int, t_h: int, t_w: int):
+    """``_compact_inverse_np`` keeping only the overlap-save output pixels:
+    ``(Kr, Ki)``, each ``(P_real, t_h * t_w)`` — the crop is folded in too,
+    so the inverse writes no pixel that is thrown away."""
+    return tuple(
+        np.ascontiguousarray(
+            k.reshape(-1, delta, delta)[:, :t_h, :t_w].reshape(len(k), -1))
+        for k in _compact_inverse_np(delta))
+
+
+def cropped_inverse_mats(delta: int, t_h: int, t_w: int):
+    """jnp copies of the folded, cropped compact-layout inverse."""
+    return tuple(jnp.asarray(m) for m in _cropped_inverse_np(delta, t_h, t_w))
+
+
+@functools.lru_cache(maxsize=None)
+def _folded_forward_np(delta: int, compact: bool = True):
+    """The tile rfft2 and its packing folded into one matrix.
+
+    Returns a float32 ``(delta * delta, 2 * P)`` matrix ``A``: for a tile
+    ``x`` flattened row-major, ``x @ A`` holds the real parts of its
+    spectrum at the ``P`` stored points, then the imaginary parts.  With
+    ``compact`` the points are the compact Hermitian list
+    (``_compact_layout_np``'s ``store``, so ``x @ A`` equals
+    ``pack_half_spectrum(*rfft2_tiles(x))``); without, the whole rect
+    ``delta x delta_h`` grid.
+    """
+    d = delta
+    dh = d // 2 + 1
+    u = np.arange(d)
+    f = np.exp(-2j * np.pi * np.outer(u, u) / d)                   # (u, h)
+    # T[u, v] = sum_{h, w} x[h, w] f[u, h] f[v, w], one column per (u, v)
+    a = np.einsum("uh,vw->hwuv", f, f[:dh]).reshape(d * d, d * dh)
+    if compact:
+        a = a[:, _compact_layout_np(d)[0]]
+    return np.concatenate([a.real, a.imag], axis=1).astype(np.float32)
+
+
+def compact_forward_mat(delta: int):
+    """jnp copy of the folded compact-layout forward ``A``."""
+    return jnp.asarray(_folded_forward_np(delta))
 
 
 def pack_half_spectrum(Tr, Ti, delta: int):

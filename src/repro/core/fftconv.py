@@ -10,6 +10,13 @@ once per weight version (``ConvPlan.prepare``):
   3. ``cgemm``             Z[p] = D[p] @ G[p]                [hot stage]
   4. ``output_inverse``    Z (P, M, C')     -> O (B,C',Ho,Wo) [irfft2 + crop]
 
+For ``spectrum="real"`` (the plan default) stages 1, 2 and 4 apply each
+tile's 2-D DFT as ONE matmul against a fixed folded matrix
+(``dft.compact_forward_mat``, ``dft.cropped_inverse_mats``): the packing,
+the conj-mirror scatter and the overlap-save crop are folded into the
+weights, so every large intermediate is written once.  The ``rect`` and
+``complex`` layouts keep the separable 16-wide matmul chain.
+
 All complex tensors are (real, imag) pairs of float arrays. ``M = B*X*Delta``
 (tile count), ``P = delta*(delta//2+1)`` frequency points.
 
@@ -26,7 +33,6 @@ from repro.core.conv_spec import ConvSpec
 from repro.core import dft
 from repro.core.dft import (
     rfft2_tiles, irfft2_tiles, fft2_full_tiles, ifft2_full_tiles,
-    pack_half_spectrum, unpack_half_spectrum,
 )
 
 
@@ -95,28 +101,61 @@ def conv2d_direct(x, k, *, padding=0, compute_dtype=None):
 # Stage 1: input transform
 # --------------------------------------------------------------------------
 
-def extract_tiles(x, spec: ConvSpec):
-    """(B, C, H, W) -> overlap-save patches (B, C, X, Delta, delta, delta)."""
+def _tile_grid(spec: ConvSpec):
+    """((top, bottom), (left, right)) padding of H and W, and the
+    overlap-save row and column indices (X, delta) / (Delta, delta) of
+    every tile in the padded input."""
     d = spec.delta
-    x = jnp.pad(x, ((0, 0), (0, 0),
-                    (spec.pad_h, spec.Hp - spec.H - spec.pad_h),
-                    (spec.pad_w, spec.Wp - spec.W - spec.pad_w)))
+    pads = ((spec.pad_h, spec.Hp - spec.H - spec.pad_h),
+            (spec.pad_w, spec.Wp - spec.W - spec.pad_w))
     h_idx = jnp.arange(spec.X)[:, None] * spec.t_h + jnp.arange(d)[None, :]
     w_idx = jnp.arange(spec.D)[:, None] * spec.t_w + jnp.arange(d)[None, :]
-    patches = x[:, :, h_idx[:, :, None, None], w_idx[None, None, :, :]]
+    return pads, h_idx[:, :, None, None], w_idx[None, None, :, :]
+
+
+def extract_tiles(x, spec: ConvSpec):
+    """(B, C, H, W) -> overlap-save patches (B, C, X, Delta, delta, delta)."""
+    pads, h_idx, w_idx = _tile_grid(spec)
+    patches = jnp.pad(x, ((0, 0), (0, 0), *pads))[:, :, h_idx, w_idx]
     # (B, C, X, delta, Delta, delta) -> (B, C, X, Delta, delta, delta)
     return patches.transpose(0, 1, 2, 4, 3, 5)
 
 
+def extract_tiles_nhwc(x, spec: ConvSpec):
+    """(B, C, H, W) -> overlap-save patches (B, X, delta, Delta, delta, C),
+    channels last, so a tile's delta*delta pixels contract against
+    ``dft.compact_forward_mat`` with the channels riding along."""
+    pads, h_idx, w_idx = _tile_grid(spec)
+    x = jnp.pad(x.transpose(0, 2, 3, 1), ((0, 0), *pads, (0, 0)))
+    return x[:, h_idx, w_idx, :]
+
+
+def _folded_forward(tiles, delta: int, eq: str):
+    """The folded tile DFT of ``tiles`` (pixel axes ``i j`` in ``eq``):
+    (real, imag) planes of the compact spectrum, the point axis ``p``."""
+    A = dft.compact_forward_mat(delta)
+    P = A.shape[-1] // 2
+    A = A.reshape(delta, delta, 2 * P)
+    return (jnp.einsum(eq, tiles, A[..., :P], precision=dft.PRECISION),
+            jnp.einsum(eq, tiles, A[..., P:], precision=dft.PRECISION))
+
+
+def transform_form(spectrum: str) -> str:
+    """How stages 1, 2 and 4 apply the tile DFT for a spectrum layout:
+    ``"folded"`` (one matmul per tile, ``spectrum="real"``) or
+    ``"separable"`` (row then column matmuls)."""
+    return "folded" if spectrum == "real" else "separable"
+
+
 def _tiles_to_spectrum(tiles, spec: ConvSpec, spectrum: str):
     """Real tile batch (..., delta, delta) -> flat spectrum planes (..., P)."""
+    if spectrum == "real":
+        return _folded_forward(tiles, spec.delta, "...ij,ijp->...p")
     if spectrum == "complex":
         Tr, Ti = fft2_full_tiles(tiles, spec.delta)
         P = spec.delta * spec.delta
         return Tr.reshape(*Tr.shape[:-2], P), Ti.reshape(*Ti.shape[:-2], P)
     Tr, Ti = rfft2_tiles(tiles, spec.delta)
-    if spectrum == "real":
-        return pack_half_spectrum(Tr, Ti, spec.delta)
     if spectrum == "rect":
         P = spec.P
         return Tr.reshape(*Tr.shape[:-2], P), Ti.reshape(*Ti.shape[:-2], P)
@@ -126,6 +165,11 @@ def _tiles_to_spectrum(tiles, spec: ConvSpec, spectrum: str):
 def input_transform(x, spec: ConvSpec, *, dtype=jnp.float32,
                     spectrum: str = "rect"):
     """Stage 1: I -> D (P, M, C) as (real, imag)."""
+    if spectrum == "real":
+        tiles = extract_tiles_nhwc(x.astype(dtype), spec)
+        return tuple(T.reshape(T.shape[0], spec.M, spec.C) for T in
+                     _folded_forward(tiles, spec.delta,
+                                     "bxiyjc,ijp->pbxyc"))
     patches = extract_tiles(x.astype(dtype), spec)     # (B, C, X, Dl, d, d)
     Tr, Ti = _tiles_to_spectrum(patches, spec, spectrum)
     P = Tr.shape[-1]                                   # == freq_count(...)
@@ -182,21 +226,33 @@ def assemble_output_tiles(y, spec: ConvSpec):
     return y[:, :, :spec.Ho, :spec.Wo]
 
 
+def _folded_inverse(Zr, Zi, spec: ConvSpec):
+    """The compact-layout inverse as ``zr @ Kr + zi @ Ki`` per tile, with
+    the conj-mirror scatter and the overlap-save crop folded into the
+    ``(P_real, t_h * t_w)`` matrices, then spatial reassembly."""
+    Kr, Ki = dft.cropped_inverse_mats(spec.delta, spec.t_h, spec.t_w)
+    P = Kr.shape[0]
+    eq = "pmc,pq->mcq"
+    y = (jnp.einsum(eq, Zr[:P], Kr, precision=dft.PRECISION)
+         + jnp.einsum(eq, Zi[:P], Ki, precision=dft.PRECISION))
+    y = y.reshape(spec.B, spec.X, spec.D, spec.Cout, spec.t_h, spec.t_w)
+    y = y.transpose(0, 3, 1, 4, 2, 5).reshape(
+        spec.B, spec.Cout, spec.X * spec.t_h, spec.D * spec.t_w)
+    return y[:, :, :spec.Ho, :spec.Wo]
+
+
 def output_inverse(Zr, Zi, spec: ConvSpec, *, spectrum: str = "rect"):
     """Stage 4: Z (P, M, C') -> O (B, C', Ho, Wo).
 
     The P axis may carry trailing padding past the layout's point count
     (nfft all-to-all divisibility); it is sliced off here.
     """
+    if spectrum == "real":
+        return _folded_inverse(Zr, Zi, spec)
     d = spec.delta
     if spectrum == "rect":
         y = irfft2_tiles(z_to_tiles(Zr[:spec.P], spec),
                          z_to_tiles(Zi[:spec.P], spec), d)
-    elif spectrum == "real":
-        P = dft.num_freq_real(d)
-        Zr, Zi = unpack_half_spectrum(z_to_flat_tiles(Zr, spec, P),
-                                      z_to_flat_tiles(Zi, spec, P), d)
-        y = irfft2_tiles(Zr, Zi, d)
     elif spectrum == "complex":
         P = d * d
         shape = (spec.B, spec.Cout, spec.X, spec.D, d, d)
