@@ -215,6 +215,7 @@ class PlanProfile:
     cgemm_shapes: Tuple = ()                   # distinct (M, N, K) at stage 3
     overlap_delta: Optional[Dict[str, Any]] = None   # vs sequential twin
     transform_forms: Tuple[str, ...] = ()      # distinct tile-DFT forms traced
+    strides: Tuple[int, ...] = ()              # distinct strides > 1 traced
 
     def describe_key(self) -> str:
         tags = [self.backend, self.schedule]
@@ -247,6 +248,7 @@ class PlanProfile:
         d["blocks"] = list(self.blocks) if self.blocks else None
         d["cgemm_shapes"] = [list(s) for s in self.cgemm_shapes]
         d["transform_forms"] = list(self.transform_forms)
+        d["strides"] = list(self.strides)
         return d
 
 
@@ -406,6 +408,14 @@ def _rule_real_spectrum_folded(p: PlanProfile) -> Optional[str]:
     return None
 
 
+def _rule_no_strided_fft(p: PlanProfile) -> Optional[str]:
+    if p.is_pipeline and p.strides:
+        return (f"stage pipeline traced stride(s) {list(p.strides)}; "
+                "overlap-save computes unit-stride outputs only (strided "
+                "layers belong on 'direct')")
+    return None
+
+
 def _rule_prepared_elides_boundary(p: PlanProfile) -> Optional[str]:
     if not (p.prepared and p.elision):
         return None
@@ -504,6 +514,9 @@ def _register_builtin_invariants() -> None:
         "*", "*", "real-spectrum-folded", _rule_real_spectrum_folded,
         "every spectrum='real' pipeline applies the tile DFT of stages 1 "
         "and 4 as one folded matmul per tile")
+    register_invariant(
+        "*", "*", "no-strided-fft", _rule_no_strided_fft,
+        "no layer with stride > 1 runs on an FFT stage pipeline")
     register_invariant(
         "*", "*", "stage-ops-once", _rule_stage_ops_once,
         "each pipeline stage op traces exactly once (stage 2 zero times "
@@ -656,6 +669,8 @@ def _profile_from_trace(plan, jaxpr, counts, *, prepared: bool):
     transform_forms = tuple(sorted(
         k[1] for k in counts
         if isinstance(k, tuple) and k[0] == "transform_form"))
+    strides = tuple(sorted(
+        k[1] for k in counts if isinstance(k, tuple) and k[0] == "stride"))
     be = registry.get_backend(plan.backend)
     return PlanProfile(
         backend=plan.backend, schedule=plan.schedule, prepared=prepared,
@@ -671,7 +686,7 @@ def _profile_from_trace(plan, jaxpr, counts, *, prepared: bool):
         overlap=getattr(plan, "overlap", "off"),
         num_slabs=getattr(plan, "num_slabs", 1),
         blocks=(plan.bm, plan.bn, plan.bk), cgemm_shapes=cgemm_shapes,
-        transform_forms=transform_forms)
+        transform_forms=transform_forms, strides=strides)
 
 
 def analyze(target, *, prepared: bool = False) -> PlanProfile:
@@ -779,7 +794,7 @@ def analyze(target, *, prepared: bool = False) -> PlanProfile:
 # --------------------------------------------------------------------------
 
 VIOLATION_MODES = ("extra-collective", "extra-stage", "skip-cast",
-                   "rfft-unpacked", "overlap-oversend")
+                   "rfft-unpacked", "overlap-oversend", "strided-fft")
 
 
 @contextlib.contextmanager
@@ -801,7 +816,11 @@ def seeded_violation(mode: str = "extra-collective"):
                         before the wire and slices back after — only
                         overlapped plans are hit (the sequential twin is
                         untouched), so the overlap-bytes-parity invariant
-                        must trip.
+                        must trip;
+      strided-fft       the planner stops sending strided layers to
+                        ``direct``: an FFT backend accepts ``stride > 1``
+                        (and computes the wrong outputs), so the
+                        no-strided-fft invariant must trip.
     """
     from repro.conv import stages
     if mode == "overlap-oversend":
@@ -872,6 +891,18 @@ def seeded_violation(mode: str = "extra-collective"):
             yield
         finally:
             dft.compact_forward_mat = orig
+    elif mode == "strided-fft":
+        from repro.conv import plan as plan_mod
+        orig = plan_mod._direct_only
+
+        def broken(kh, kw, delta, stride):
+            return orig(kh, kw, delta, 1)
+
+        plan_mod._direct_only = broken
+        try:
+            yield
+        finally:
+            plan_mod._direct_only = orig
     elif mode == "skip-cast":
         orig = stages._maybe_cast
 
@@ -913,6 +944,7 @@ def sweep(*, batch: int = 4, limit: Optional[int] = None,
     ``--jobs`` process-parallel tracer partitions the registry this way."""
     import jax.numpy as jnp
     from repro.compat import make_mesh
+    from repro.conv import plan as plan_mod
     from repro.conv import registry
     from repro.conv.epilogue import Epilogue
     from repro.conv.plan import plan_conv
@@ -940,7 +972,14 @@ def sweep(*, batch: int = 4, limit: Optional[int] = None,
             ]
             if cdt is not None:
                 variants.append(("cdtype", {"compute_dtype": cdt}, False))
-            if registry.get_backend(backend).pipeline_factory is not None:
+            # a strided layer, where the planner allows one: on direct,
+            # and on a pipeline only when _direct_only stops refusing it
+            # (--inject strided-fft), which must trip no-strided-fft
+            pipeline = registry.get_backend(backend).pipeline_factory
+            if pipeline is None or plan_mod._direct_only(
+                    k_shape[2], k_shape[3], 16, 2) is None:
+                variants.append(("stride2", {"stride": 2}, False))
+            if pipeline is not None:
                 # the full-spectrum twin is a legal plan in its own right
                 # — certify it directly, not only as a ratio baseline
                 variants.append(("complex", {"spectrum": "complex"}, False))

@@ -250,9 +250,9 @@ def _dtype_name(dtype) -> str:
 
 
 def spec_signature(x_shape, k_shape, *, padding=(0, 0), delta: int = 16,
-                   schedule: str = "auto", mesh=None, three_m: bool = True,
-                   compute_dtype=None, data_axis: str = "data",
-                   model_axis: str = "model",
+                   stride: int = 1, schedule: str = "auto", mesh=None,
+                   three_m: bool = True, compute_dtype=None,
+                   data_axis: str = "data", model_axis: str = "model",
                    replicate_kernel_transform: bool = False,
                    spectrum: str = "auto", overlap: str = "off",
                    bm=None, bn=None, bk=None, dft_bt=None) -> str:
@@ -261,11 +261,13 @@ def spec_signature(x_shape, k_shape, *, padding=(0, 0), delta: int = 16,
     precision, kernel-transform placement, requested spectrum, pinned
     blocks).  Two calls that could legally get different winners must get
     different signatures — a pin-constrained sweep must never answer for
-    an unconstrained one."""
+    an unconstrained one.  Unit stride adds nothing to the signature, so
+    persisted unit-stride entries keep their keys."""
     pad = _normalize_padding(padding)
+    strided = f"|stride={int(stride)}" if int(stride) != 1 else ""
     return (f"v{CACHE_VERSION}"
             f"|x={tuple(map(int, x_shape))}|k={tuple(map(int, k_shape))}"
-            f"|pad={pad}|delta={int(delta)}|sched={schedule}"
+            f"|pad={pad}{strided}|delta={int(delta)}|sched={schedule}"
             f"|mesh={_mesh_signature(mesh)}|3m={int(bool(three_m))}"
             f"|dtype={_dtype_name(compute_dtype)}"
             f"|axes={data_axis},{model_axis}"
@@ -330,7 +332,13 @@ def candidates(spec: ConvSpec, *, schedule: str = "auto", mesh=None,
     schedules and ``direct`` have nothing to overlap and stay ``off``.
     Overlapped Pallas candidates are timed at default blocks only (the
     planner re-pins blocks against the sub-slab shape, so sweeping block
-    variants per slab count would square the Pallas tail of the sweep)."""
+    variants per slab count would square the Pallas tail of the sweep).
+    A spec only ``direct`` can run (a stride) has one candidate,
+    ``direct`` on ``local``."""
+    from repro.conv import plan
+    if plan._direct_only(spec.kh, spec.kw, spec.delta, spec.stride):
+        return [_merge_pins(TunedConfig("direct", "local", spectrum="real"),
+                            bm, bn, bk, dft_bt)]
     if schedule != "auto":
         scheds = [schedule]
     else:
@@ -419,8 +427,8 @@ def measure_us(fn, *args, reps: int = _DEFAULT_REPS, **kwargs) -> float:
 
 
 def _measure_candidate(cand: TunedConfig, x_shape, k_shape, *, padding,
-                       delta, mesh, three_m, compute_dtype, data_axis,
-                       model_axis, replicate_kernel_transform,
+                       delta, stride, mesh, three_m, compute_dtype,
+                       data_axis, model_axis, replicate_kernel_transform,
                        reps) -> float:
     """Time one candidate through the real planner with a representative
     bias+relu epilogue (exercises the fused ``dft_tile`` tail, so
@@ -430,8 +438,9 @@ def _measure_candidate(cand: TunedConfig, x_shape, k_shape, *, padding,
     from repro.conv.epilogue import Epilogue
     from repro.conv.plan import plan_conv
     plan = plan_conv(x_shape, k_shape, padding=padding, delta=delta,
-                     backend=cand.backend, schedule=cand.schedule,
-                     mesh=mesh, three_m=three_m, bm=cand.bm, bn=cand.bn,
+                     stride=stride, backend=cand.backend,
+                     schedule=cand.schedule, mesh=mesh, three_m=three_m,
+                     bm=cand.bm, bn=cand.bn,
                      bk=cand.bk, dft_bt=cand.dft_bt,
                      spectrum=cand.spectrum, overlap=cand.overlap,
                      compute_dtype=compute_dtype, data_axis=data_axis,
@@ -465,8 +474,8 @@ def _cost_model_config(spec: ConvSpec, schedule: str, mesh, three_m,
 
 
 def tune(spec, k_shape=None, *, padding=None, delta: Optional[int] = None,
-         schedule: str = "auto", mesh=None, three_m: bool = True,
-         compute_dtype=None, data_axis: str = "data",
+         stride: Optional[int] = None, schedule: str = "auto", mesh=None,
+         three_m: bool = True, compute_dtype=None, data_axis: str = "data",
          model_axis: str = "model",
          replicate_kernel_transform: bool = False,
          spectrum: str = "auto", overlap: str = "off",
@@ -480,20 +489,23 @@ def tune(spec, k_shape=None, *, padding=None, delta: Optional[int] = None,
     measurement later re-tunes.
 
     ``spec`` is the same first positional ``plan_conv`` takes: either a
-    ``ConvSpec`` (geometry + padding + delta in one object) or the input
-    shape ``(B, C, H, W)`` with ``k_shape``/``padding``/``delta`` given
-    separately.
+    ``ConvSpec`` (geometry + padding + delta + stride in one object) or
+    the input shape ``(B, C, H, W)`` with ``k_shape``/``padding``/
+    ``delta``/``stride`` given separately.
     """
     global _hits, _misses, _fallbacks, _measured
     if isinstance(spec, ConvSpec):
-        if k_shape is not None or padding is not None or delta is not None:
+        if (k_shape is not None or padding is not None or delta is not None
+                or stride is not None):
             raise TypeError(
                 "tune(spec, ...): a ConvSpec already carries k_shape/"
-                "padding/delta — pass them only with the shape-tuple form")
+                "padding/delta/stride — pass them only with the shape-tuple "
+                "form")
         x_shape = (spec.B, spec.C, spec.H, spec.W)
         k_shape = (spec.Cout, spec.C, spec.kh, spec.kw)
         padding = (spec.pad_h, spec.pad_w)
         delta = spec.delta
+        stride = spec.stride
     else:
         if k_shape is None:
             raise TypeError(
@@ -502,11 +514,12 @@ def tune(spec, k_shape=None, *, padding=None, delta: Optional[int] = None,
         x_shape = spec
         padding = (0, 0) if padding is None else padding
         delta = 16 if delta is None else delta
+        stride = 1 if stride is None else int(stride)
     x_shape = tuple(map(int, x_shape))
     k_shape = tuple(map(int, k_shape))
     padding = _normalize_padding(padding)
-    key_kwargs = dict(padding=padding, delta=delta, schedule=schedule,
-                      mesh=mesh, three_m=three_m,
+    key_kwargs = dict(padding=padding, delta=delta, stride=stride,
+                      schedule=schedule, mesh=mesh, three_m=three_m,
                       compute_dtype=compute_dtype, data_axis=data_axis,
                       model_axis=model_axis,
                       replicate_kernel_transform=replicate_kernel_transform,
@@ -520,7 +533,7 @@ def tune(spec, k_shape=None, *, padding=None, delta: Optional[int] = None,
             _hits += 1
         return hit
 
-    spec = _make_spec(x_shape, k_shape, padding, delta)
+    spec = _make_spec(x_shape, k_shape, padding, delta, stride)
     if not autotune_enabled():
         with _lock:
             _fallbacks += 1
@@ -542,8 +555,9 @@ def tune(spec, k_shape=None, *, padding=None, delta: Optional[int] = None,
         try:
             us = _measure_candidate(
                 cand, x_shape, k_shape, padding=padding, delta=delta,
-                mesh=mesh, three_m=three_m, compute_dtype=compute_dtype,
-                data_axis=data_axis, model_axis=model_axis,
+                stride=stride, mesh=mesh, three_m=three_m,
+                compute_dtype=compute_dtype, data_axis=data_axis,
+                model_axis=model_axis,
                 replicate_kernel_transform=replicate_kernel_transform,
                 reps=reps)
         except Exception as e:

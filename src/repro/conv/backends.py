@@ -95,8 +95,10 @@ def _pallas_fused_inverse_real(Zr, Zi, spec, epilogue, bias, *, bt=None):
 def _exec_direct(plan, x, k, bias=None, residual=None):
     # named like the fft stage ops (``repro.conv.stages._stage``), so a
     # profiler trace attributes the conv and its epilogue to ``direct``
+    stages.count_stride(plan.spec.stride)
     with jax.named_scope("direct"):
         y = F.conv2d_direct(x, k, padding=plan.padding,
+                            stride=plan.spec.stride,
                             compute_dtype=plan.compute_dtype)
         out_dtype = y.dtype
         return apply_epilogue(y, plan.epilogue, bias=bias,

@@ -125,6 +125,7 @@ def plan_config(plan) -> dict:
         "x_shape": list(plan.x_shape),
         "k_shape": list(plan.k_shape),
         "padding": list(plan.padding),
+        "stride": int(plan.spec.stride),
         "delta": int(plan.spec.delta),
         "backend": plan.backend,
         "schedule": plan.schedule,
@@ -154,6 +155,7 @@ def rebuild_plan(cfg: dict):
     return plan_conv(
         tuple(cfg["x_shape"]), tuple(cfg["k_shape"]),
         padding=tuple(cfg["padding"]), delta=int(cfg["delta"]),
+        stride=int(cfg.get("stride", 1)),
         backend=cfg["backend"], schedule=cfg["schedule"], mesh=mesh,
         three_m=cfg["three_m"], bm=cfg["bm"], bn=cfg["bn"], bk=cfg["bk"],
         dft_bt=cfg["dft_bt"],

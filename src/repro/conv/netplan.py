@@ -65,12 +65,14 @@ class NetworkConv:
     padding: Any = 0
     epilogue: Epilogue = Epilogue()
     overrides: tuple = ()        # (("backend", "direct"), ...) — hashable
+    stride: int = 1
 
     def plan_kwargs(self, shared: dict) -> dict:
         kw = dict(shared)
         kw.update(dict(self.overrides))
         kw["padding"] = self.padding
         kw["epilogue"] = self.epilogue
+        kw["stride"] = self.stride
         return kw
 
 
@@ -233,7 +235,8 @@ class NetworkPlan:
                 for ov_req in (plan.overlap, "auto"):
                     c = autotune.lookup(
                         plan.x_shape, plan.k_shape, padding=plan.padding,
-                        delta=plan.spec.delta, schedule=sched_req,
+                        delta=plan.spec.delta, stride=plan.spec.stride,
+                        schedule=sched_req,
                         mesh=plan.mesh, three_m=plan.three_m,
                         compute_dtype=plan.compute_dtype,
                         data_axis=plan.data_axis,

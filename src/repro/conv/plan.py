@@ -225,7 +225,7 @@ class ConvPlan:
         lines = [
             f"ConvPlan {self.x_shape} * {self.k_shape} -> {self.out_shape}",
             f"  backend={self.backend} schedule={self.schedule} "
-            f"three_m={self.three_m} delta={s.delta} "
+            f"stride={s.stride} three_m={self.three_m} delta={s.delta} "
             f"spectrum={self.spectrum} epilogue={self.epilogue.describe()}",
             f"  cost-model FLOPs: direct {s.direct_flops():.3e}, fft "
             f"{s.cgemm_flops(three_m=self.three_m) + s.transform_flops():.3e}",
@@ -379,7 +379,7 @@ def _normalize_padding(padding) -> tuple:
     return (int(ph), int(pw))
 
 
-def _build_spec(x_shape, k_shape, padding, delta) -> ConvSpec:
+def _build_spec(x_shape, k_shape, padding, delta, stride=1) -> ConvSpec:
     """Validated ``ConvSpec`` for a conv geometry (shared with the
     autotuner so cache signatures can never drift from planner
     semantics).  Kernels larger than the tile get a widened (then-unused)
@@ -390,11 +390,25 @@ def _build_spec(x_shape, k_shape, padding, delta) -> ConvSpec:
         raise ValueError(f"channel mismatch: input C={C}, kernel C={C2}")
     return ConvSpec(B=B, C=C, Cout=Cout, H=H, W=W, kh=kh, kw=kw,
                     pad_h=padding[0], pad_w=padding[1],
-                    delta=max(delta, kh, kw))
+                    delta=max(delta, kh, kw), stride=stride)
+
+
+def _direct_only(kh: int, kw: int, delta: int, stride: int):
+    """Why only ``direct`` can run a geometry, or ``None``.  Overlap-save
+    computes every unit-stride output, so the FFT pipelines take neither
+    a kernel larger than the tile nor a stride."""
+    if max(kh, kw) > delta:
+        return f"kernel {kh}x{kw} exceeds tile size delta={delta}"
+    if stride != 1:
+        return (f"stride {stride} is not expressible by overlap-save "
+                "(it computes every unit-stride output)")
+    return None
 
 
 def _auto_backend(spec: ConvSpec, three_m: bool) -> str:
     """Direct-vs-FFT crossover on the ConvSpec cost model."""
+    if _direct_only(spec.kh, spec.kw, spec.delta, spec.stride):
+        return "direct"
     fft = spec.cgemm_flops(three_m=three_m) + spec.transform_flops()
     return "direct" if spec.direct_flops() <= fft else "fft-xla"
 
@@ -453,7 +467,7 @@ def _resolve_overlap(overlap, spec, sched, be, backend, schedule, mesh,
 def _resolve(x_shape, k_shape, padding, delta, backend, schedule, mesh,
              three_m, bm, bn, bk, dft_bt, compute_dtype, data_axis,
              model_axis, replicate_kernel_transform, epilogue,
-             spectrum, overlap="off") -> ConvPlan:
+             spectrum, overlap="off", stride=1) -> ConvPlan:
     _, _, kh, kw = k_shape
     if spectrum == "auto":
         spectrum = "real"    # compact Hermitian layout is the default path
@@ -461,16 +475,17 @@ def _resolve(x_shape, k_shape, padding, delta, backend, schedule, mesh,
         raise ValueError(
             f"unknown spectrum {spectrum!r} (choose 'real', 'complex', or "
             "'auto')")
-    # Kernels larger than the FFT tile rule out the FFT backends but are
-    # fine for direct conv: _build_spec widens the (then-unused) tile so
-    # the spec validates, and auto resolves to direct below.
-    oversize = max(kh, kw) > delta
-    if oversize and backend not in ("auto", "direct"):
+    # Kernels larger than the FFT tile and strides rule out the FFT
+    # backends but are fine for direct conv: _build_spec widens the
+    # (then-unused) tile so the spec validates, and auto resolves to
+    # direct below.
+    direct_only = _direct_only(kh, kw, delta, stride)
+    if direct_only and backend not in ("auto", "direct"):
         registry.get_backend(backend)        # unknown names error first
         raise ValueError(
-            f"kernel {kh}x{kw} exceeds tile size delta={delta}; only the "
-            f"'direct' backend supports it (requested {backend!r})")
-    spec = _build_spec(x_shape, k_shape, padding, delta)
+            f"{direct_only}; only the 'direct' backend supports it "
+            f"(requested {backend!r})")
+    spec = _build_spec(x_shape, k_shape, padding, delta, stride)
 
     # -- schedule -----------------------------------------------------------
     if schedule == "auto":
@@ -493,7 +508,7 @@ def _resolve(x_shape, k_shape, padding, delta, backend, schedule, mesh,
 
     # -- backend ------------------------------------------------------------
     if backend == "auto":
-        if oversize:
+        if direct_only:
             backend = "direct"
         else:
             backend = "fft-xla" if sched.requires_mesh \
@@ -550,6 +565,7 @@ def plan_conv(spec, k_shape=None, *, padding=None, delta: Optional[int] = None,
               epilogue: Optional[Epilogue] = None,
               spectrum: str = "auto",
               overlap: str = "off",
+              stride: Optional[int] = None,
               cache: bool = True) -> ConvPlan:
     """Create (or fetch from the plan cache) a ``ConvPlan``.
 
@@ -562,6 +578,9 @@ def plan_conv(spec, k_shape=None, *, padding=None, delta: Optional[int] = None,
         (shape-tuple form only — a ``ConvSpec`` already carries it).
       padding: int or ``(ph, pw)`` zero padding (default 0).
       delta: FFT tile size (the paper uses 16).
+      stride: output subsampling, the same in both axes (default 1).
+        Only ``direct`` runs ``stride > 1``: ``"auto"`` and ``"tuned"``
+        resolve to it and the FFT backends raise ``ValueError``.
       backend: ``"direct"`` | ``"fft-xla"`` | ``"fft-pallas"`` | ``"auto"``
         (cost-model crossover; never auto-selects Pallas) | ``"tuned"``
         (measured on-device selection via ``repro.conv.autotune`` — warm
@@ -614,14 +633,17 @@ def plan_conv(spec, k_shape=None, *, padding=None, delta: Optional[int] = None,
     """
     global _cache_hits, _cache_misses
     if isinstance(spec, ConvSpec):
-        if k_shape is not None or padding is not None or delta is not None:
+        if (k_shape is not None or padding is not None or delta is not None
+                or stride is not None):
             raise TypeError(
                 "plan_conv(spec, ...): a ConvSpec already carries k_shape/"
-                "padding/delta — pass them only with the shape-tuple form")
+                "padding/delta/stride — pass them only with the shape-tuple "
+                "form")
         x_shape = (spec.B, spec.C, spec.H, spec.W)
         k_shape = (spec.Cout, spec.C, spec.kh, spec.kw)
         padding = (spec.pad_h, spec.pad_w)
         delta = spec.delta
+        stride = spec.stride
     else:
         if k_shape is None:
             raise TypeError(
@@ -630,6 +652,7 @@ def plan_conv(spec, k_shape=None, *, padding=None, delta: Optional[int] = None,
         x_shape = spec
         padding = 0 if padding is None else padding
         delta = 16 if delta is None else delta
+        stride = 1 if stride is None else int(stride)
     x_shape, k_shape = tuple(map(int, x_shape)), tuple(map(int, k_shape))
     padding = _normalize_padding(padding)
     epilogue = Epilogue() if epilogue is None else epilogue
@@ -638,8 +661,8 @@ def plan_conv(spec, k_shape=None, *, padding=None, delta: Optional[int] = None,
         # is memoized under the *resolved* config: a cost-model fallback
         # (measurement disabled / cold-and-offline) is never frozen in —
         # once the tuning cache warms, the next call adopts the winner.
-        if max(k_shape[2], k_shape[3]) > delta:
-            backend = "direct"      # oversize kernel: only direct fits
+        if _direct_only(k_shape[2], k_shape[3], delta, stride):
+            backend = "direct"      # oversize kernel or stride: only direct
         else:
             from repro.conv import autotune
             # tune unpinned: pins constrain the *plan*, not the machine's
@@ -668,7 +691,7 @@ def plan_conv(spec, k_shape=None, *, padding=None, delta: Optional[int] = None,
     key = (x_shape, k_shape, padding, delta, backend, schedule,
            _mesh_cache_key(mesh), three_m, bm, bn, bk, dft_bt,
            compute_dtype, data_axis, model_axis,
-           replicate_kernel_transform, epilogue, spectrum, overlap)
+           replicate_kernel_transform, epilogue, spectrum, overlap, stride)
     if cache:
         with _cache_lock:
             plan = _plan_cache.get(key)
@@ -679,7 +702,7 @@ def plan_conv(spec, k_shape=None, *, padding=None, delta: Optional[int] = None,
     plan = _resolve(x_shape, k_shape, padding, delta, backend, schedule,
                     mesh, three_m, bm, bn, bk, dft_bt, compute_dtype,
                     data_axis, model_axis, replicate_kernel_transform,
-                    epilogue, spectrum, overlap)
+                    epilogue, spectrum, overlap, stride)
     if cache:
         with _cache_lock:
             _cache_misses += 1

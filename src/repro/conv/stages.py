@@ -57,7 +57,9 @@ Traces also record dtype facts as ``("cgemm_dtype", <dtype>)`` tuple keys
 alongside the plain string op counts — the static analyzer reads these to
 certify that ``compute_dtype`` actually reached the hot stage — and, from
 stages 1 and 4, ``("transform_form", "folded" | "separable")``: how the
-tile DFT was applied (``repro.core.fftconv.transform_form``).
+tile DFT was applied (``repro.core.fftconv.transform_form``), and, from
+stage 1 or a ``direct`` layer whose spec has ``stride > 1``,
+``("stride", s)``.
 """
 from __future__ import annotations
 
@@ -128,8 +130,16 @@ def _stage(name: str):
         yield
 
 
+def count_stride(stride: int) -> None:
+    """Record a strided layer for the analyzer; unit stride records
+    nothing."""
+    if stride != 1:
+        _count(("stride", stride))
+
+
 def stage_input_transform(x, spec: ConvSpec, spectrum: str = "rect"):
     _count(("transform_form", F.transform_form(spectrum)))
+    count_stride(spec.stride)
     with _stage("input_transform"):
         return F.input_transform(x, spec, spectrum=spectrum)
 
@@ -214,7 +224,7 @@ def _pad_axis(x, axis, mult):
 def _local_spec(spec: ConvSpec, b_loc: int, c_loc: int, co_loc: int):
     return ConvSpec(B=b_loc, C=c_loc, Cout=co_loc, H=spec.H, W=spec.W,
                     kh=spec.kh, kw=spec.kw, pad_h=spec.pad_h,
-                    pad_w=spec.pad_w, delta=spec.delta)
+                    pad_w=spec.pad_w, delta=spec.delta, stride=spec.stride)
 
 
 def padded_sharded_spec(plan) -> ConvSpec:
@@ -229,7 +239,7 @@ def padded_sharded_spec(plan) -> ConvSpec:
     return ConvSpec(
         B=s.B + (-s.B) % n_data, C=s.C + (-s.C) % n_model,
         Cout=s.Cout + (-s.Cout) % n_model, H=s.H, W=s.W, kh=s.kh, kw=s.kw,
-        pad_h=s.pad_h, pad_w=s.pad_w, delta=s.delta)
+        pad_h=s.pad_h, pad_w=s.pad_w, delta=s.delta, stride=s.stride)
 
 
 def _place(pair, plan, pspec):

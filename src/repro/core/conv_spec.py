@@ -11,6 +11,9 @@ class ConvSpec:
 
     Overlap-save with tile ``delta x delta``: every tile of the padded input
     yields a ``t x t`` block of valid outputs, ``t = delta - k + 1``.
+    ``stride`` subsamples the output in both axes.  The tile grid covers
+    the unit-stride output whatever the stride: overlap-save computes
+    every unit-stride output, so only ``direct`` runs a strided spec.
     """
     B: int
     C: int
@@ -22,8 +25,11 @@ class ConvSpec:
     pad_h: int = 0
     pad_w: int = 0
     delta: int = 16
+    stride: int = 1
 
     def __post_init__(self):
+        if self.stride < 1:
+            raise ValueError(f"stride must be >= 1, got {self.stride}")
         if self.kh > self.delta or self.kw > self.delta:
             raise ValueError(
                 f"kernel {self.kh}x{self.kw} exceeds tile size {self.delta}")
@@ -39,19 +45,19 @@ class ConvSpec:
 
     @property
     def Ho(self) -> int:
-        return self.H + 2 * self.pad_h - self.kh + 1
+        return (self.H + 2 * self.pad_h - self.kh) // self.stride + 1
 
     @property
     def Wo(self) -> int:
-        return self.W + 2 * self.pad_w - self.kw + 1
+        return (self.W + 2 * self.pad_w - self.kw) // self.stride + 1
 
     @property
     def X(self) -> int:                # tile grid rows
-        return math.ceil(self.Ho / self.t_h)
+        return math.ceil((self.H + 2 * self.pad_h - self.kh + 1) / self.t_h)
 
     @property
     def D(self) -> int:                # tile grid cols (paper's Delta)
-        return math.ceil(self.Wo / self.t_w)
+        return math.ceil((self.W + 2 * self.pad_w - self.kw + 1) / self.t_w)
 
     @property
     def n_tiles(self) -> int:

@@ -71,11 +71,12 @@ def freq_count(spec: ConvSpec, spectrum: str = "rect") -> int:
 # Oracle
 # --------------------------------------------------------------------------
 
-def conv2d_direct(x, k, *, padding=0, compute_dtype=None):
+def conv2d_direct(x, k, *, padding=0, stride=1, compute_dtype=None):
     """Direct convolution oracle: lax.conv_general_dilated, NCHW/OIHW.
 
     ``padding`` is an int or ``(pad_h, pad_w)``, symmetric per axis —
     the same convention as the FFT path (lax wants (lo, hi) per dim).
+    ``stride`` is the same in both spatial axes.
     ``compute_dtype`` casts the operands (f32 accumulation, result back in
     ``x.dtype``) — the direct-backend analogue of the FFT schedules' hot
     CGEMM operand cast.  Runs at the engine's matmul precision
@@ -89,7 +90,7 @@ def conv2d_direct(x, k, *, padding=0, compute_dtype=None):
         x, k = x.astype(compute_dtype), k.astype(compute_dtype)
         acc = dict(preferred_element_type=jnp.float32)
     y = jax.lax.conv_general_dilated(
-        x, k, window_strides=(1, 1),
+        x, k, window_strides=(stride, stride),
         padding=[(pad[0], pad[0]), (pad[1], pad[1])],
         dimension_numbers=("NCHW", "OIHW", "NCHW"),
         precision=dft.PRECISION, **acc,

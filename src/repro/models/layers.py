@@ -67,6 +67,19 @@ def maxpool2x2(x):
                                  (1, 1, 2, 2), (1, 1, 2, 2), "VALID")
 
 
+def maxpool3x3s2(x):
+    """3x3/stride-2 max pool with 1 pixel of padding (which never wins)
+    over the spatial axes of NCHW ``x``: the ResNet stem's pool."""
+    return jax.lax.reduce_window(x, -jnp.inf, jax.lax.max,
+                                 (1, 1, 3, 3), (1, 1, 2, 2),
+                                 ((0, 0), (0, 0), (1, 1), (1, 1)))
+
+
+def global_avgpool(x):
+    """Mean over the spatial axes of NCHW ``x``, kept as a 1x1 map."""
+    return jnp.mean(x, axis=(2, 3), keepdims=True)
+
+
 def conv_block(x, k, bias=None, *, activation="none", residual=None,
                padding=1, backend="auto", schedule="auto", mesh=None,
                compute_dtype=None, weights_version=None):

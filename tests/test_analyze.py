@@ -195,6 +195,8 @@ def test_seeded_violation_is_caught(mode):
     kw = {"compute_dtype": jnp.bfloat16} if mode == "skip-cast" else {}
     if mode == "overlap-oversend":
         kw["overlap"] = "slab:2"       # only overlapped plans hit the slab ops
+    if mode == "strided-fft":
+        kw["stride"] = 2               # only strided plans reach the guard
     with seeded_violation(mode):
         p = analyze(plan_conv((2, 4, 22, 22), (4, 4, 3, 3), padding=1,
                               backend="fft-xla", schedule="nfft",
