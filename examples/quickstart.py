@@ -2,9 +2,10 @@
 
     PYTHONPATH=src python examples/quickstart.py
 
-``plan_conv`` picks the algorithm (direct vs FFT) from the geometry's cost
-model, freezes the schedule, and the returned plan executes (and
-differentiates) like a plain function. Plans are cached by shape, so
+``plan_conv`` freezes the algorithm (direct or FFT; ``backend="auto"``
+picks the one with the smaller predicted device time) and the schedule,
+and the returned plan executes (and differentiates) like a plain
+function. Plans are cached by shape, so
 planning inside a layer loop is free after the first call.
 """
 import jax
@@ -21,7 +22,7 @@ rng = np.random.default_rng(0)
 x = jnp.asarray(rng.standard_normal((2, 64, 56, 56)), jnp.float32)
 k = jnp.asarray(rng.standard_normal((128, 64, 3, 3)), jnp.float32)
 
-plan = plan_conv(x.shape, k.shape, padding=1)      # backend="auto"
+plan = plan_conv(x.shape, k.shape, padding=1, backend="fft-xla")
 y_fft = plan(x, k)                                 # execute
 y_ref = conv2d_direct(x, k, padding=1)             # direct oracle
 
@@ -34,10 +35,11 @@ print(f"tiling: {spec.X}x{spec.D} tiles of {spec.delta}x{spec.delta}, "
       f"P={freq_count(spec, plan.spectrum)} frequency points "
       f"({plan.spectrum} layout), CGEMM {spec.M}x{spec.C}x{spec.Cout}")
 
-# The cost model sends small geometries to the direct backend instead.
-tiny = plan_conv((1, 3, 16, 16), (4, 3, 1, 1))
-print(f"auto backend for a 1x1-kernel layer: {tiny.backend} "
-      f"(vs {plan.backend} for the VGG layer)")
+# backend="auto" picks by predicted device time: a 64-channel layer runs
+# faster on direct; the FFT wins with wide channels and many tiles.
+for shapes in ((x.shape, k.shape), ((32, 512, 28, 28), (512, 512, 3, 3))):
+    auto = plan_conv(*shapes, padding=1)
+    print(f"auto backend for {shapes[0]} * {shapes[1]}: {auto.backend}")
 
 # Plans are differentiable on every backend x schedule (plan-level VJP).
 def loss(k):

@@ -1,6 +1,6 @@
 """On-device measured autotuner for the conv engine (``backend="tuned"``).
 
-The cost model behind ``backend="auto"`` ranks candidates by FLOPs, but the
+The cost model behind ``backend="auto"`` predicts each backend's time, but the
 direct/FFT crossover — and the best (schedule, block) configuration — is
 machine-dependent (Zlateski et al.).  This module *measures* instead:
 
@@ -314,7 +314,7 @@ def _merge_pins(cand: TunedConfig, bm, bn, bk, dft_bt) -> TunedConfig:
 
 
 def candidates(spec: ConvSpec, *, schedule: str = "auto", mesh=None,
-               three_m: bool = True, spectrum: str = "auto",
+               spectrum: str = "auto",
                overlap: str = "off",
                bm=None, bn=None, bk=None, dft_bt=None) -> list:
     """Enumerate the tuning space, cost-model pick first (so a clamped
@@ -394,7 +394,7 @@ def candidates(spec: ConvSpec, *, schedule: str = "auto", mesh=None,
             uniq.append(c)
     # cost-model pick first (``_auto_backend`` never picks Pallas, so the
     # pick is always a single candidate), Pallas variants last
-    pick = _cost_model_pick(spec, scheds[0], three_m)
+    pick = _cost_model_pick(spec, scheds[0])
     uniq.sort(key=lambda c: 0 if ((c.backend, c.schedule) == pick
                                   and c.spectrum == "real"
                                   and c.overlap == "off")
@@ -402,11 +402,11 @@ def candidates(spec: ConvSpec, *, schedule: str = "auto", mesh=None,
     return uniq
 
 
-def _cost_model_pick(spec: ConvSpec, sched: str, three_m: bool) -> tuple:
+def _cost_model_pick(spec: ConvSpec, sched: str) -> tuple:
     from repro.conv.plan import _auto_backend
     if sched != "local":
         return ("fft-xla", sched)
-    return (_auto_backend(spec, three_m), sched)
+    return (_auto_backend(spec), sched)
 
 
 # --------------------------------------------------------------------------
@@ -459,11 +459,11 @@ def _measure_candidate(cand: TunedConfig, x_shape, k_shape, *, padding,
 # The tuner
 # --------------------------------------------------------------------------
 
-def _cost_model_config(spec: ConvSpec, schedule: str, mesh, three_m,
+def _cost_model_config(spec: ConvSpec, schedule: str, mesh,
                        spectrum, overlap, bm, bn, bk, dft_bt) -> TunedConfig:
     if schedule == "auto":
         schedule = "nfft" if mesh is not None else "local"
-    backend, _ = _cost_model_pick(spec, schedule, three_m)
+    backend, _ = _cost_model_pick(spec, schedule)
     if spectrum == "auto" or backend == "direct":
         spectrum = "real"               # compact layout is the engine default
     if overlap == "auto":
@@ -537,12 +537,12 @@ def tune(spec, k_shape=None, *, padding=None, delta: Optional[int] = None,
     if not autotune_enabled():
         with _lock:
             _fallbacks += 1
-        return _cost_model_config(spec, schedule, mesh, three_m,
+        return _cost_model_config(spec, schedule, mesh,
                                   spectrum, overlap, bm, bn, bk, dft_bt)
     with _lock:
         _misses += 1
 
-    cands = candidates(spec, schedule=schedule, mesh=mesh, three_m=three_m,
+    cands = candidates(spec, schedule=schedule, mesh=mesh,
                        spectrum=spectrum, overlap=overlap,
                        bm=bm, bn=bn, bk=bk, dft_bt=dft_bt)
     budget = budget_ms() if budget is None else float(budget)

@@ -9,8 +9,8 @@ Schedules:
          all-reduce inside the hot stage.
 
 Backends:
-  direct      lax.conv_general_dilated (the oracle path; wins for small
-              channel counts / tiny kernels by the cost model).  Opaque
+  direct      lax.conv_general_dilated (the oracle path; auto's pick up
+              to a few hundred channels by predicted time).  Opaque
               execute, native XLA autodiff; the plan epilogue is applied
               right after the conv (XLA fuses the elementwise tail).
   fft-xla     the paper's 4-stage pipeline composed from repro.conv.stages

@@ -25,7 +25,8 @@ all-to-all #2 zero times.  The cache is keyed by ``weights_version``:
 prepare with a new version recomputes (invalidation), with the same
 version returns the cached ``PreparedConv``.
 
-``backend="auto"`` picks direct vs FFT from the ``ConvSpec`` cost model;
+``backend="auto"`` picks direct or FFT by predicted device time
+(``predicted_s``, from ``ConvSpec``'s FLOP and byte counts);
 ``schedule="auto"`` picks ``nfft`` when a mesh is given, else ``local``.
 ``backend="tuned"`` replaces the cost model with *measured* selection
 (``repro.conv.autotune``): candidate (backend, schedule, block) configs are
@@ -216,9 +217,8 @@ class ConvPlan:
         """Cost-model FLOPs of the planned path (for rooflines)."""
         if self.backend == "direct":
             return self.spec.direct_flops()
-        return self.spec.cgemm_flops(three_m=self.three_m,
-                                     spectrum=self.spectrum) \
-            + self.spec.transform_flops()
+        return sum(self.spec.stage_flops(self.spectrum,
+                                         three_m=self.three_m).values())
 
     def describe(self) -> str:
         s = self.spec
@@ -227,8 +227,8 @@ class ConvPlan:
             f"  backend={self.backend} schedule={self.schedule} "
             f"stride={s.stride} three_m={self.three_m} delta={s.delta} "
             f"spectrum={self.spectrum} epilogue={self.epilogue.describe()}",
-            f"  cost-model FLOPs: direct {s.direct_flops():.3e}, fft "
-            f"{s.cgemm_flops(three_m=self.three_m) + s.transform_flops():.3e}",
+            "  predicted ms: " + ", ".join(
+                f"{be} {t * 1e3:.3f}" for be, t in predicted_s(s).items()),
         ]
         if self.mesh is not None:
             n_data = self.mesh.shape[self.data_axis]
@@ -405,12 +405,53 @@ def _direct_only(kh: int, kw: int, delta: int, stride: int):
     return None
 
 
-def _auto_backend(spec: ConvSpec, three_m: bool) -> str:
-    """Direct-vs-FFT crossover on the ConvSpec cost model."""
+# backend="auto" predicts each backend's device time from ConvSpec's
+# counts: every stage takes max(FLOPs x passes / (peak x MXU fill),
+# bytes / bandwidth).  TPU v5e (Google Cloud, "TPU v5e"): 197 TFLOP/s
+# bf16, 819 GB/s HBM, a 128 x 128 MXU; an f32 matmul at HIGHEST takes about
+# six bf16 passes (direct runs at ~32 TFLOP/s of direct count with 512
+# channels).  The FFT stages move ~3x the bytes ConvSpec counts (pads and
+# layout copies around the matmuls): the one constant fitted to both
+# backends' per-layer times on one v5e (PERF.md, "auto's table").
+_PEAK_FLOPS = 197e12
+_PASSES = 6
+_HBM_BYTES_S = 819e9
+_MXU = 128
+_FFT_BYTES_SCALE = 3.0
+
+
+def _fill(n: int) -> float:
+    """Share of the MXU's rows (or columns) a dimension of ``n`` fills."""
+    return n / (_MXU * -(-n // _MXU))
+
+
+def _stage_s(flops: int, nbytes: float, fill: float) -> float:
+    return max(flops * _PASSES / (_PEAK_FLOPS * fill), nbytes / _HBM_BYTES_S)
+
+
+def predicted_s(spec: ConvSpec) -> dict:
+    """Predicted device seconds of one prepared forward on ``direct`` and
+    on ``fft-xla`` (stage 2 runs once per weight version, so it is left
+    out).  ``direct`` contracts C x kh x kw against Cout; the transforms
+    put the P' spectrum points (and t_h x t_w outputs) on the MXU, the
+    CGEMM C and Cout."""
+    direct = _stage_s(spec.direct_flops(), spec.direct_bytes(),
+                      _fill(spec.C * spec.kh * spec.kw) * _fill(spec.Cout))
+    f, b = spec.stage_flops("real"), spec.stage_bytes("real")
+    P = spec.freq_points("real")
+    fft = sum(_stage_s(f[s], b[s] * _FFT_BYTES_SCALE, fill) for s, fill in (
+        ("input_transform", _fill(P)),
+        ("cgemm", _fill(spec.C) * _fill(spec.Cout)),
+        ("output_inverse", _fill(P) * _fill(spec.t_h * spec.t_w))))
+    return {"direct": direct, "fft-xla": fft}
+
+
+def _auto_backend(spec: ConvSpec) -> str:
+    """The backend with the smaller predicted time (``predicted_s``)."""
     if _direct_only(spec.kh, spec.kw, spec.delta, spec.stride):
         return "direct"
-    fft = spec.cgemm_flops(three_m=three_m) + spec.transform_flops()
-    return "direct" if spec.direct_flops() <= fft else "fft-xla"
+    t = predicted_s(spec)
+    return "direct" if t["direct"] <= t["fft-xla"] else "fft-xla"
 
 
 # overlap="auto" picks "off" below this per-rank batch: slabbing a tiny
@@ -512,7 +553,7 @@ def _resolve(x_shape, k_shape, padding, delta, backend, schedule, mesh,
             backend = "direct"
         else:
             backend = "fft-xla" if sched.requires_mesh \
-                else _auto_backend(spec, three_m)
+                else _auto_backend(spec)
     be = registry.get_backend(backend)
     if schedule not in be.schedules:
         raise ValueError(
@@ -582,7 +623,8 @@ def plan_conv(spec, k_shape=None, *, padding=None, delta: Optional[int] = None,
         Only ``direct`` runs ``stride > 1``: ``"auto"`` and ``"tuned"``
         resolve to it and the FFT backends raise ``ValueError``.
       backend: ``"direct"`` | ``"fft-xla"`` | ``"fft-pallas"`` | ``"auto"``
-        (cost-model crossover; never auto-selects Pallas) | ``"tuned"``
+        (the smaller predicted time, ``predicted_s``; never auto-selects
+        Pallas) | ``"tuned"``
         (measured on-device selection via ``repro.conv.autotune`` — warm
         persistent cache, cost-model fallback when measurement is
         disabled; the tuner also picks schedule and blocks unless pinned

@@ -97,19 +97,64 @@ class ConvSpec:
             return d * d // 2 + 2 if d % 2 == 0 else (d * d + 1) // 2
         raise ValueError(f"unknown spectrum {spectrum!r}")
 
-    # ---- cost model (for roofline / napkin math) --------------------------
+    # ---- cost model: what the plans run -----------------------------------
+    # FLOPs count every multiply and add of the stage as the plans lower it
+    # (``tests/test_conv_plan.py`` holds them to XLA's ``cost_analysis``);
+    # bytes count each stage's HBM traffic at 4 bytes a float32 value.
     def direct_flops(self) -> int:
         return 2 * self.B * self.Cout * self.C * self.Ho * self.Wo * self.kh * self.kw
 
+    def direct_bytes(self) -> int:
+        """x and k read, y written."""
+        return 4 * (self.B * self.C * self.H * self.W
+                    + self.Cout * self.C * self.kh * self.kw
+                    + self.B * self.Cout * self.Ho * self.Wo)
+
     def cgemm_flops(self, three_m: bool = False,
-                    spectrum: str = "rect") -> int:
-        per_point = (6 if three_m else 8) * self.M * self.C * self.Cout
+                    spectrum: str = "real") -> int:
+        """Per point: 3M is three real matmuls, the operand sums Dr + Di
+        and Gr + Gi, and three output subtractions; 4M four matmuls and
+        two output adds."""
+        M, C, Co = self.M, self.C, self.Cout
+        if three_m:
+            per_point = 6 * M * C * Co + M * C + C * Co + 3 * M * Co
+        else:
+            per_point = 8 * M * C * Co + 2 * M * Co
         return self.freq_points(spectrum) * per_point
 
-    def transform_flops(self) -> int:
-        # input + kernel + inverse transforms, 6 small matmuls each ~2*d^3-ish
-        d, dh = self.delta, self.delta_h
-        per_tile = 2 * d * d * d * 2 + 4 * 2 * d * d * dh   # fwd: F@x (2) + A@Fh (4)
-        inv_per_tile = 4 * 2 * d * d * dh + 2 * 2 * d * dh * d
-        return (self.B * self.n_tiles * self.C + self.C * self.Cout) * per_tile \
-            + self.B * self.n_tiles * self.Cout * inv_per_tile
+    def _tile_dft_flops(self, spectrum: str) -> tuple:
+        """(forward per real delta x delta tile, inverse per output tile)."""
+        d = self.delta
+        if spectrum == "real":
+            # folded: one (d*d, P) matmul per plane forward; zr @ Kr +
+            # zi @ Ki against the cropped (P, t_h*t_w) mats inverse
+            P, t = self.freq_points("real"), self.t_h * self.t_w
+            return 4 * d * d * P, 4 * P * t + t
+        # separable row-then-column chain (rect and complex layouts)
+        dh = self.delta_h
+        return (2 * d * d * d * 2 + 4 * 2 * d * d * dh,
+                4 * 2 * d * d * dh + 2 * 2 * d * dh * d)
+
+    def stage_flops(self, spectrum: str = "real",
+                    three_m: bool = True) -> dict:
+        """FLOPs of each FFT stage (stage 2 once per weight version)."""
+        fwd, inv = self._tile_dft_flops(spectrum)
+        return {"input_transform": self.M * self.C * fwd,
+                "kernel_transform": self.C * self.Cout * fwd,
+                "cgemm": self.cgemm_flops(three_m=three_m, spectrum=spectrum),
+                "output_inverse": self.M * self.Cout * inv}
+
+    def stage_bytes(self, spectrum: str = "real") -> dict:
+        """HBM bytes of each FFT stage a prepared forward runs: stage 1
+        reads x, writes and reads the overlap-save tiles and writes the
+        spectrum; stage 3 reads the spectrum and G and writes Z; stage 4
+        reads Z and writes y."""
+        P, d = self.freq_points(spectrum), self.delta
+        x = self.B * self.C * self.H * self.W
+        y = self.B * self.Cout * self.Ho * self.Wo
+        D, G, Z = (2 * P * self.M * self.C, 2 * P * self.C * self.Cout,
+                   2 * P * self.M * self.Cout)
+        return {k: 4 * v for k, v in (
+            ("input_transform", x + 2 * self.M * d * d * self.C + D),
+            ("cgemm", D + G + Z),
+            ("output_inverse", Z + y))}

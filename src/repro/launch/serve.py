@@ -345,9 +345,9 @@ def main(argv=None):
     ap.add_argument("--convnet", choices=["vgg"], default=None,
                     help="serve the paper's conv trunk via plan_network "
                          "instead of an LM arch")
-    # "auto" matches the planner's cost-model default, so untuned smoke
-    # runs resolve per-geometry (direct for tiny layers, fft-xla past the
-    # crossover) instead of forcing one backend; --tune overrides this
+    # "auto" matches the planner's default, so untuned smoke runs resolve
+    # per-geometry (the backend with the smaller predicted time) instead
+    # of forcing one backend; --tune overrides this
     # with measured per-geometry winners (backend="tuned").
     ap.add_argument("--conv-backend", default="auto")
     ap.add_argument("--serve-trace", action="store_true",

@@ -1,5 +1,5 @@
 """Plan/execute conv engine: cache semantics, registry validation,
-(backend, schedule) equivalence grid, and the cost-model auto crossover."""
+(backend, schedule) equivalence grid, and auto's predicted-time crossover."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,6 +11,7 @@ from repro.conv import (
     plan_cache_capacity, available_backends, available_schedules,
     register_backend,
 )
+from repro.conv.plan import predicted_s
 from repro.core import conv2d_direct
 
 
@@ -226,24 +227,73 @@ def test_replicate_kernel_transform_single_device():
 # --------------------------------------------------------------------------
 
 def test_auto_backend_crossover():
-    # tiny 1x1 kernel: transforms dwarf the direct cost -> direct
+    # tiny 1x1 kernel: the transforms dwarf the direct cost -> direct
     small = plan_conv((1, 3, 16, 16), (4, 3, 1, 1))
     assert small.backend == "direct"
-    assert small.spec.direct_flops() <= \
-        small.spec.cgemm_flops(three_m=True) + small.spec.transform_flops()
-    # VGG-scale 3x3 layer: FFT path is cheaper -> fft-xla
-    big = plan_conv((4, 128, 56, 56), (128, 128, 3, 3), padding=1)
+    # 128 channels at 56 wide: direct fills the MXU, the FFT stages are
+    # bound by their bytes -> direct
+    mid = plan_conv((4, 128, 56, 56), (128, 128, 3, 3), padding=1)
+    assert mid.backend == "direct"
+    # 512 channels, 28 wide at batch 32 (VGG conv4_2): the FFT path's
+    # 2.8x fewer FLOPs outweigh its bytes -> fft-xla
+    big = plan_conv((32, 512, 28, 28), (512, 512, 3, 3), padding=1)
     assert big.backend == "fft-xla"
-    assert big.spec.direct_flops() > \
-        big.spec.cgemm_flops(three_m=True) + big.spec.transform_flops()
-    # both execute correctly through whatever auto picked
-    for plan, seed in ((small, 5), (big, 7)):
+    for plan in (small, mid, big):
+        t = predicted_s(plan.spec)
+        assert min(t, key=t.get) == plan.backend
+        assert f"direct {t['direct'] * 1e3:.3f}" in plan.describe()
+    # both backends execute the mid geometry correctly
+    fft = plan_conv(mid.x_shape, mid.k_shape, padding=1, backend="fft-xla")
+    for plan, seed in ((small, 5), (mid, 7), (fft, 7)):
         x = _rand(plan.x_shape, seed)
         k = _rand(plan.k_shape, seed + 1)
         np.testing.assert_allclose(
             np.asarray(plan(x, k)),
             np.asarray(conv2d_direct(x, k, padding=plan.padding)),
             rtol=2e-3, atol=2e-3)
+
+
+# Every unit-stride layer geometry of the benchmark's three configurations
+# at its cell's batch, with the backend that ran it faster on a TPU v5e
+# (PERF.md, "auto's table"; three ties within 2% go to direct), then
+# measured cases on both sides of the crossover.
+# B, C, Cout, H (= W), k, pad -> backend
+AUTO_PICKS = [
+    ((32, 3, 64, 224, 3, 1), "direct"),         # VGG-16 conv1_1
+    ((32, 64, 64, 224, 3, 1), "direct"),        # conv1_2
+    ((32, 64, 128, 112, 3, 1), "direct"),       # conv2_1
+    ((32, 128, 128, 112, 3, 1), "direct"),      # conv2_2
+    ((32, 128, 256, 56, 3, 1), "direct"),       # conv3_1
+    ((32, 256, 256, 56, 3, 1), "direct"),       # conv3_2, conv3_3
+    ((32, 256, 512, 28, 3, 1), "direct"),       # conv4_1 (fft 1.8% faster)
+    ((32, 512, 512, 28, 3, 1), "fft-xla"),      # conv4_2, conv4_3
+    ((32, 512, 512, 14, 3, 1), "direct"),       # conv5_x
+    ((128, 64, 64, 56, 3, 1), "direct"),        # ResNet-34 layer1, Rconv2.2
+    ((128, 128, 128, 28, 3, 1), "direct"),      # layer2, Rconv3.2
+    ((128, 256, 256, 14, 3, 1), "direct"),      # layer3 (fft 0.9% faster)
+    ((128, 512, 512, 7, 3, 1), "direct"),       # layer4, Rconv5.2
+    ((128, 48, 128, 27, 5, 2), "direct"),       # AlexNet Aconv2
+    ((128, 256, 384, 13, 3, 1), "direct"),      # Aconv3 (fft 1.9% faster)
+    ((128, 192, 192, 13, 3, 1), "direct"),      # Aconv4
+    ((128, 192, 128, 13, 3, 1), "direct"),      # Aconv5
+    # conv4_2's geometry at batch 1: G's bytes are not amortized
+    ((1, 512, 512, 28, 3, 1), "direct"),
+    # 256 channels at 28 wide, batch 128: too few channels for the FFT
+    ((128, 256, 256, 28, 3, 1), "direct"),
+    ((128, 160, 160, 28, 3, 1), "direct"),
+    ((4, 64, 64, 224, 3, 1), "direct"),
+]
+
+
+@pytest.mark.parametrize("geom,want", AUTO_PICKS,
+                         ids=lambda v: "x".join(map(str, v))
+                         if isinstance(v, tuple) else v)
+def test_auto_picks_the_faster_backend(geom, want):
+    B, C, Co, H, k, pad = geom
+    plan = plan_conv((B, C, H, H), (Co, C, k, k), padding=pad)
+    assert plan.backend == want
+    t = predicted_s(plan.spec)
+    assert min(t, key=t.get) == want
 
 
 def test_oversize_kernel_routes_to_direct():
@@ -271,14 +321,12 @@ def test_plan_metadata_and_flops():
                      backend="fft-xla")
     assert plan.out_shape == (2, 4, 20, 20)
     assert plan.differentiable
-    assert plan.flops() == \
-        plan.spec.cgemm_flops(three_m=True, spectrum=plan.spectrum) \
-        + plan.spec.transform_flops()
-    # the compact Hermitian layout is the default and is cheaper than the
-    # historical rect rfft2 grid
+    assert plan.flops() == sum(plan.spec.stage_flops().values())
+    # the compact Hermitian layout is the default, and its CGEMM runs
+    # fewer points than the historical rect rfft2 grid
     assert plan.spectrum == "real"
-    assert plan.flops() < plan.spec.cgemm_flops(three_m=True) \
-        + plan.spec.transform_flops()
+    assert plan.spec.cgemm_flops(three_m=True) \
+        < plan.spec.cgemm_flops(three_m=True, spectrum="rect")
     direct = plan_conv((2, 8, 20, 20), (4, 8, 3, 3), padding=1,
                        backend="direct")
     assert direct.flops() == direct.spec.direct_flops()
@@ -288,6 +336,42 @@ def test_plan_metadata_and_flops():
     pallas = plan_conv((2, 8, 20, 20), (4, 8, 3, 3), padding=1,
                        backend="fft-pallas")
     assert pallas.differentiable
+
+
+def _xla_flops(f, *shapes):
+    c = jax.jit(f).lower(*(jax.ShapeDtypeStruct(s, jnp.float32)
+                           for s in shapes)).compile()
+    cost = c.cost_analysis()
+    return (cost[0] if isinstance(cost, list) else cost)["flops"]
+
+
+@pytest.mark.parametrize("three_m", [True, False])
+@pytest.mark.parametrize("x_shape,k_shape,pad", [
+    ((2, 8, 20, 20), (16, 8, 3, 3), 1),
+    ((1, 5, 13, 13), (7, 5, 5, 5), 2),
+])
+def test_stage_flops_match_xla_cost_analysis(x_shape, k_shape, pad, three_m):
+    """``ConvSpec.stage_flops`` counts what the folded stage functions
+    lower to: XLA's own count of each stage agrees within 1%."""
+    from repro.core import fftconv as F
+    from repro.core.cgemm import cgemm
+    spec = plan_conv(x_shape, k_shape, padding=pad, backend="fft-xla").spec
+    P, M, C, Co = spec.freq_points("real"), spec.M, spec.C, spec.Cout
+    want = spec.stage_flops("real", three_m=three_m)
+    got = {
+        "input_transform": _xla_flops(
+            lambda x: F.input_transform(x, spec, spectrum="real"), x_shape),
+        "kernel_transform": _xla_flops(
+            lambda k: F.kernel_transform(k, spec, spectrum="real"), k_shape),
+        "cgemm": _xla_flops(
+            lambda a, b, c, d: cgemm(a, b, c, d, three_m=three_m),
+            (P, M, C), (P, M, C), (P, C, Co), (P, C, Co)),
+        "output_inverse": _xla_flops(
+            lambda zr, zi: F._folded_inverse(zr, zi, spec),
+            (P, M, Co), (P, M, Co)),
+    }
+    for stage, n in want.items():
+        assert got[stage] == pytest.approx(n, rel=0.01), stage
 
 
 def test_plan_gradients_match_direct():

@@ -100,9 +100,11 @@ def test_auto_plans_strided_layers_on_direct_and_unit_layers_on_fft():
     backends = {n: net[n].backend for n in net}
     assert all(backends[n] == "direct"
                for n in net if net[n].spec.stride > 1)
-    fft = sorted(n for n, b in backends.items() if b == "fft-xla")
-    assert len(fft) == 24 and all(n.startswith(("layer1", "layer2",
-                                                "layer3")) for n in fft)
+    # by predicted chip time the unit-stride 3x3 layers (64-512
+    # channels, 56 to 7 wide at batch 128) run faster on direct
+    unit = [n for n in net if net[n].spec.stride == 1 and n != "fc"]
+    assert len(unit) == 29
+    assert all(backends[n] == "direct" for n in unit)
 
 
 def test_pools():
