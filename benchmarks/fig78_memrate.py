@@ -34,6 +34,8 @@ B, C, Co, H, W, kh, pad = (spec[k] for k in
                            ("B", "C", "Co", "H", "W", "kh", "pad"))
 cs = make_spec((B, C, H, W), (Co, C, kh, kh), pad)
 n_model = 4
+# spectrum points, padded to a model-axis multiple as the nfft schedule does
+NP = cs.freq_points() + (-cs.freq_points()) % n_model
 rng = np.random.default_rng(0)
 
 
@@ -44,10 +46,10 @@ def mk(shape, pspec):
 
 out = {}
 # --- nFFT hot stage: P sharded over model, M over data; local einsum ------
-Dr = mk((cs.P, cs.M, C), P("model", "data", None))
-Di = mk((cs.P, cs.M, C), P("model", "data", None))
-Gr = mk((cs.P, C, Co), P("model", None, None))
-Gi = mk((cs.P, C, Co), P("model", None, None))
+Dr = mk((NP, cs.M, C), P("model", "data", None))
+Di = mk((NP, cs.M, C), P("model", "data", None))
+Gr = mk((NP, C, Co), P("model", None, None))
+Gi = mk((NP, C, Co), P("model", None, None))
 f_n = jax.jit(
     shard_map(lambda a, b, c, d: cgemm(a, b, c, d),
               mesh=mesh,
@@ -56,10 +58,10 @@ f_n = jax.jit(
               out_specs=(P("model", "data", None),
                          P("model", "data", None))))
 # --- wFFT hot stage: C sharded over model -> psum inside ------------------
-Dr2 = mk((cs.P, cs.M, C), P(None, "data", "model"))
-Di2 = mk((cs.P, cs.M, C), P(None, "data", "model"))
-Gr2 = mk((cs.P, C, Co), P(None, "model", None))
-Gi2 = mk((cs.P, C, Co), P(None, "model", None))
+Dr2 = mk((NP, cs.M, C), P(None, "data", "model"))
+Di2 = mk((NP, cs.M, C), P(None, "data", "model"))
+Gr2 = mk((NP, C, Co), P(None, "model", None))
+Gi2 = mk((NP, C, Co), P(None, "model", None))
 
 
 def wfft_body(a, b, c, d):

@@ -91,8 +91,6 @@ def main(argv=None) -> dict:
         sys.stdout.flush()
         fig78_memrate.main()
         sys.stdout.flush()
-        _spectrum_rows(quick=args.quick)
-        sys.stdout.flush()
     finally:
         sys.stdout = tee.wrapped
 
@@ -146,7 +144,7 @@ def _tuned_rows(quick: bool = True) -> dict:
         us = w.us_per_call
         config = {"backend": w.backend, "schedule": w.schedule,
                   "bm": w.bm, "bn": w.bn, "bk": w.bk, "dft_bt": w.dft_bt,
-                  "spectrum": w.spectrum, "source": w.source}
+                  "source": w.source}
         if us is None:
             # cost-model fallback (measurement disabled): time the pick so
             # the row still carries a number
@@ -162,30 +160,6 @@ def _tuned_rows(quick: bool = True) -> dict:
         print(f"{name},{us:.1f},{config['backend']}/{config['schedule']}")
         out[name] = {"us_per_call": float(us), "config": config}
     return out
-
-
-def _spectrum_rows(quick: bool = True):
-    """Real (compact Hermitian) vs complex (full-spectrum twin) frequency
-    layout on bandwidth-bound Table-I geometries, same backend/schedule —
-    isolating what the rfft fast path buys."""
-    import jax.numpy as jnp
-    import numpy as np
-    from repro.conv import autotune, plan_conv
-
-    layers = [("vgg-conv3.2", (1, 256, 56, 56), (256, 256, 3, 3), 1)]
-    if not quick:
-        layers.append(("vgg-conv4.2", (1, 512, 28, 28), (512, 512, 3, 3), 1))
-    print("# spectrum: compact-Hermitian (real) vs full-spectrum (complex), "
-          "fft-xla/local — name,us_per_call,spectrum")
-    rng = np.random.default_rng(0)
-    for name, x_shape, k_shape, padding in layers:
-        x = jnp.asarray(rng.standard_normal(x_shape), jnp.float32)
-        k = jnp.asarray(rng.standard_normal(k_shape), jnp.float32)
-        for spectrum in ("real", "complex"):
-            plan = plan_conv(x_shape, k_shape, padding=padding,
-                             backend="fft-xla", spectrum=spectrum)
-            us = autotune.measure_us(plan, x, k, reps=2 if quick else 3)
-            print(f"spectrum/{name}/{spectrum},{us:.1f},{spectrum}")
 
 
 _OVERLAP_WORKER = r"""

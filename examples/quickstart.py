@@ -14,7 +14,6 @@ import numpy as np
 
 from repro.conv import plan_conv, plan_cache_info
 from repro.core import conv2d_direct
-from repro.core.fftconv import freq_count
 
 rng = np.random.default_rng(0)
 
@@ -32,8 +31,8 @@ print(plan.describe())
 
 spec = plan.spec
 print(f"tiling: {spec.X}x{spec.D} tiles of {spec.delta}x{spec.delta}, "
-      f"P={freq_count(spec, plan.spectrum)} frequency points "
-      f"({plan.spectrum} layout), CGEMM {spec.M}x{spec.C}x{spec.Cout}")
+      f"P={spec.freq_points()} frequency points (compact half-spectrum), "
+      f"CGEMM {spec.M}x{spec.C}x{spec.Cout}")
 
 # backend="auto" picks by predicted device time: a 64-channel layer runs
 # faster on direct; the FFT wins with wide channels and many tiles.

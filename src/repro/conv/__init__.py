@@ -22,7 +22,6 @@ from repro.conv.stages import stage_trace
 from repro.conv.netplan import (
     NetworkConv, NetworkPlan, NetworkProfile, PreparedNetwork,
     BucketedNetworkPlan, plan_network,
-    plan_network_buckets, prepare_network_buckets, bucket_report,
 )
 from repro.conv.export import (
     ArtifactMismatch, LoadedConv, LoadedNetwork, export_network,
@@ -42,7 +41,6 @@ __all__ = [
     "ConvPlan", "PreparedConv", "plan_conv", "conv2d", "Epilogue",
     "NetworkConv", "NetworkPlan", "NetworkProfile", "PreparedNetwork",
     "BucketedNetworkPlan", "plan_network",
-    "plan_network_buckets", "prepare_network_buckets", "bucket_report",
     "ArtifactMismatch", "LoadedConv", "LoadedNetwork", "export_network",
     "load_network", "plan_fingerprint",
     "plan_cache_info", "clear_plan_cache", "plan_cache_capacity",

@@ -43,9 +43,10 @@ On top of the profile sits a declarative invariant registry keyed by
                               CGEMM operands in compute_dtype
     *        * + epilogue     zero extra collectives, zero extra stage ops
     *        *                no f64 anywhere in the traced program
-    *        nfft (real)      <= 0.55x the boundary all-to-all bytes of
-                              the plan's full-spectrum (complex) twin
-    *        wfft (real)      <= 0.55x the hot psum bytes of the twin
+    *        nfft             collective bytes == the count from the
+                              geometry (``counted_collective_bytes``)
+    *        wfft             collective bytes == the count from the
+                              geometry
 
 Overlapped plans (``overlap="slab:k"``) scale the count rules per slab —
 nfft traces ``4k + 2`` all_to_all eqns (D/Z boundaries per slab, kernel
@@ -56,15 +57,18 @@ the rows, they must never re-send them), and on ``fft-pallas`` every
 sub-slab's cgemm must resolve the one plan-pinned block config (no
 per-slab re-padding).
 
-The real-spectrum rules are *relative*: ``analyze`` traces the same plan
-with ``spectrum="complex"`` (``dataclasses.replace`` twin) and compares
-collective operand bytes — certifying that the compact Hermitian packing
-actually halves what the wires move, not merely that it exists.
+The collective-bytes rules are *exact*: ``counted_collective_bytes``
+counts what each sharded schedule's collectives must move from the
+plan's geometry — ``spec.freq_points()`` compact spectrum points, the
+mesh padding of batch, channels and (nfft) the point axis,
+``compute_dtype``, and the prepared / replicated / sub-slab form — and
+the traced operand bytes must equal it, so a spectrum that carries one
+redundant point more than the compact list trips the gate.
 
 ``python -m repro.conv.analyze --check`` sweeps every registered
 backend x schedule pair over the paper geometries
 (``configs/paper_convs.py``) x {full, prepared, fused-epilogue,
-compute-dtype, complex-spectrum} variants and exits non-zero on any
+compute-dtype, strided, overlapped} variants and exits non-zero on any
 violation — the CI gate
 that keeps future perf work honest.  ``seeded_violation(...)`` breaks the
 pipelines on purpose so the gate itself is testable.
@@ -207,14 +211,12 @@ class PlanProfile:
     n_eqns: int
     epilogue_delta: Optional[Dict[str, Dict[str, int]]] = None
     elision: Optional[Dict[str, int]] = None   # full minus prepared counts
-    spectrum: str = "real"                     # plan frequency layout
-    spectrum_delta: Optional[Dict[str, Any]] = None  # vs complex twin
+    counted_collective_bytes: Optional[int] = None   # from the geometry
     overlap: str = "off"                       # plan overlap knob (resolved)
     num_slabs: int = 1                         # sub-slab count (1 = off)
     blocks: Optional[Tuple] = None             # plan (bm, bn, bk) pins
     cgemm_shapes: Tuple = ()                   # distinct (M, N, K) at stage 3
     overlap_delta: Optional[Dict[str, Any]] = None   # vs sequential twin
-    transform_forms: Tuple[str, ...] = ()      # distinct tile-DFT forms traced
     strides: Tuple[int, ...] = ()              # distinct strides > 1 traced
 
     def describe_key(self) -> str:
@@ -223,8 +225,6 @@ class PlanProfile:
             tags.append("prepared")
         if self.num_slabs > 1:
             tags.append(self.overlap)
-        if self.spectrum != "real":
-            tags.append(self.spectrum)
         if self.epilogue != "none":
             tags.append(f"ep={self.epilogue}")
         if self.compute_dtype:
@@ -247,7 +247,6 @@ class PlanProfile:
         d["cgemm_dtypes"] = list(self.cgemm_dtypes)
         d["blocks"] = list(self.blocks) if self.blocks else None
         d["cgemm_shapes"] = [list(s) for s in self.cgemm_shapes]
-        d["transform_forms"] = list(self.transform_forms)
         d["strides"] = list(self.strides)
         return d
 
@@ -381,30 +380,13 @@ def _rule_epilogue_free(p: PlanProfile) -> Optional[str]:
     return "; ".join(bad) or None
 
 
-_RFFT_BYTES_RATIO = 0.55
-
-
-def _rule_rfft_halves_collective_bytes(p: PlanProfile) -> Optional[str]:
-    if p.spectrum != "real" or not p.spectrum_delta:
+def _rule_collective_bytes_counted(p: PlanProfile) -> Optional[str]:
+    if p.counted_collective_bytes is None:
         return None
-    ratio = p.spectrum_delta.get("ratio")
-    if ratio is not None and ratio > _RFFT_BYTES_RATIO:
-        return (f"real-spectrum plan moves {ratio:.4f}x the collective "
-                f"bytes of its full-spectrum twin "
-                f"({p.spectrum_delta.get('collective_bytes')} vs "
-                f"{p.spectrum_delta.get('twin_collective_bytes')}); the "
-                f"compact Hermitian packing must stay <= "
-                f"{_RFFT_BYTES_RATIO}x")
-    return None
-
-
-def _rule_real_spectrum_folded(p: PlanProfile) -> Optional[str]:
-    if not p.is_pipeline or p.spectrum != "real":
-        return None
-    if set(p.transform_forms) != {"folded"}:
-        return (f"real-spectrum stages 1/4 traced tile-DFT forms "
-                f"{sorted(set(p.transform_forms))}; expected only "
-                f"'folded' (one matmul per tile)")
+    if p.collective_bytes != p.counted_collective_bytes:
+        return (f"collectives moved {p.collective_bytes} operand bytes; "
+                f"the geometry counts {p.counted_collective_bytes} "
+                f"(spec.freq_points() points, mesh padding, compute_dtype)")
     return None
 
 
@@ -501,19 +483,15 @@ def _register_builtin_invariants() -> None:
         _rule_cast_before_hot_collective("psum", _wfft_psum),
         "compute_dtype cast lands before the hot-stage psum pair")
     register_invariant(
-        "*", "nfft", "nfft-rfft-halves-a2a",
-        _rule_rfft_halves_collective_bytes,
-        "the compact half-spectrum nfft plan moves <= 0.55x the boundary "
-        "all-to-all bytes of its full-spectrum (complex) twin")
+        "*", "nfft", "nfft-collective-bytes",
+        _rule_collective_bytes_counted,
+        "the boundary all-to-alls move exactly the bytes counted from the "
+        "compact spectrum, the mesh padding and compute_dtype")
     register_invariant(
-        "*", "wfft", "wfft-rfft-halves-psum",
-        _rule_rfft_halves_collective_bytes,
-        "the compact half-spectrum wfft plan moves <= 0.55x the hot psum "
-        "bytes of its full-spectrum (complex) twin")
-    register_invariant(
-        "*", "*", "real-spectrum-folded", _rule_real_spectrum_folded,
-        "every spectrum='real' pipeline applies the tile DFT of stages 1 "
-        "and 4 as one folded matmul per tile")
+        "*", "wfft", "wfft-collective-bytes",
+        _rule_collective_bytes_counted,
+        "the hot psum pair moves exactly the bytes counted from the "
+        "compact spectrum, the mesh padding and compute_dtype")
     register_invariant(
         "*", "*", "no-strided-fft", _rule_no_strided_fft,
         "no layer with stride > 1 runs on an FFT stage pipeline")
@@ -625,6 +603,40 @@ def _trace_prepared(plan, state=None):
     return jaxpr, dict(counts)
 
 
+def counted_collective_bytes(plan, *, prepared: bool) -> Optional[int]:
+    """Operand bytes a sharded stage pipeline's collectives must move on
+    one rank, counted from the geometry (``None`` off nfft / wfft).
+
+    The spectrum has ``P = spec.freq_points()`` points; batch and both
+    channel axes are padded to mesh-axis multiples, and nfft pads P to a
+    model-axis multiple before its boundaries.  Every (re, im) pair
+    counts twice.  D and Z travel in ``compute_dtype`` when set, the
+    kernel boundary always in float32; sub-slabs split the batch rows,
+    so their sum is the sequential count.
+    """
+    import numpy as np
+    from repro.conv import registry
+    from repro.conv.stages import padded_sharded_spec
+    if registry.get_backend(plan.backend).pipeline_factory is None \
+            or plan.schedule not in ("nfft", "wfft"):
+        return None
+    spec = padded_sharded_spec(plan)
+    n_data = plan.mesh.shape[plan.data_axis]
+    n_model = plan.mesh.shape[plan.model_axis]
+    rows = spec.B // n_data * spec.n_tiles          # M on one rank
+    hot = 4 if plan.compute_dtype is None \
+        else np.dtype(plan.compute_dtype).itemsize
+    P = spec.freq_points()
+    if plan.schedule == "wfft":
+        return 2 * P * rows * spec.Cout * hot        # the psum pair on Z
+    P_pad = P + (-P) % n_model
+    d = 2 * P_pad * rows * (spec.C // n_model) * hot     # boundary 1
+    z = 2 * (P_pad // n_model) * rows * spec.Cout * hot  # boundary 3
+    g = 0 if (prepared or plan.replicate_kernel_transform) \
+        else 2 * P_pad * spec.C * (spec.Cout // n_model) * 4  # boundary 2
+    return d + z + g
+
+
 def _profile_from_trace(plan, jaxpr, counts, *, prepared: bool):
     import numpy as np
     from repro.conv import registry
@@ -666,9 +678,6 @@ def _profile_from_trace(plan, jaxpr, counts, *, prepared: bool):
     cgemm_shapes = tuple(sorted(
         k[1] for k in counts if isinstance(k, tuple) and k[0] == "cgemm_shape"
     ))
-    transform_forms = tuple(sorted(
-        k[1] for k in counts
-        if isinstance(k, tuple) and k[0] == "transform_form"))
     strides = tuple(sorted(
         k[1] for k in counts if isinstance(k, tuple) and k[0] == "stride"))
     be = registry.get_backend(plan.backend)
@@ -682,11 +691,12 @@ def _profile_from_trace(plan, jaxpr, counts, *, prepared: bool):
         collective_bytes=coll_bytes, stage_counts=stage_counts,
         cgemm_dtypes=cgemm_dtypes, has_f64=f64[0],
         peak_live_bytes=_peak_live_bytes(jaxpr.jaxpr), n_eqns=n_eqns[0],
-        spectrum=getattr(plan, "spectrum", "real"),
+        counted_collective_bytes=counted_collective_bytes(
+            plan, prepared=prepared),
         overlap=getattr(plan, "overlap", "off"),
         num_slabs=getattr(plan, "num_slabs", 1),
         blocks=(plan.bm, plan.bn, plan.bk), cgemm_shapes=cgemm_shapes,
-        transform_forms=transform_forms, strides=strides)
+        strides=strides)
 
 
 def analyze(target, *, prepared: bool = False) -> PlanProfile:
@@ -748,26 +758,6 @@ def analyze(target, *, prepared: bool = False) -> PlanProfile:
         }
         profile = dataclasses.replace(profile, epilogue_delta=delta)
 
-    # Real-spectrum plans on sharded schedules get a bytes-ratio profile
-    # against their full-spectrum twin (same plan, spectrum="complex") so
-    # the halved-collective-bytes invariant is certified *relatively* —
-    # the twin is traced at the same prepared-ness, never executed.
-    if profile.is_pipeline and plan.spectrum == "real" \
-            and plan.schedule in ("nfft", "wfft"):
-        twin = dataclasses.replace(plan, spectrum="complex")
-        if prepared:
-            tp = _profile_from_trace(twin, *_trace_prepared(twin),
-                                     prepared=True)
-        else:
-            tp = _profile_from_trace(twin, *_trace_full(twin),
-                                     prepared=False)
-        ratio = (profile.collective_bytes / tp.collective_bytes
-                 if tp.collective_bytes else None)
-        profile = dataclasses.replace(profile, spectrum_delta={
-            "collective_bytes": profile.collective_bytes,
-            "twin_collective_bytes": tp.collective_bytes,
-            "ratio": ratio})
-
     # Overlapped plans get a bytes-parity profile against their sequential
     # twin (same plan, overlap="off"): the sub-slab collectives must
     # repartition the rows the synchronous path moves, never re-send them.
@@ -808,10 +798,10 @@ def seeded_violation(mode: str = "extra-collective"):
       skip-cast         compute_dtype casts silently dropped (collectives
                         move full-width bytes again);
       rfft-unpacked     the folded forward keeps the whole rect
-                        half-plane (delta x (delta//2+1) points) —
-                        real-spectrum plans ship the redundant
-                        self-conjugate rows again and the bytes-ratio
-                        invariants must trip;
+                        half-plane (delta x (delta//2+1) points) — the
+                        plans ship the redundant self-conjugate rows
+                        again and the collective-bytes invariants must
+                        trip;
       overlap-oversend  every sub-slab collective pads its M rows 2x
                         before the wire and slices back after — only
                         overlapped plans are hit (the sequential twin is
@@ -865,9 +855,9 @@ def seeded_violation(mode: str = "extra-collective"):
     elif mode == "extra-stage":
         orig = stages.stage_kernel_transform
 
-        def broken(k, spec, spectrum="rect"):
-            orig(k, spec, spectrum)
-            return orig(k, spec, spectrum)
+        def broken(k, spec):
+            orig(k, spec)
+            return orig(k, spec)
 
         stages.stage_kernel_transform = broken
         try:
@@ -937,7 +927,7 @@ def sweep(*, batch: int = 4, limit: Optional[int] = None,
           compute_dtype="bfloat16", progress=print, pairs=None):
     """Profile + check every registered backend x schedule pair over the
     paper geometries x {full, prepared, fused-epilogue, compute-dtype,
-    full-spectrum (complex), overlapped (slab:2)} variants.  Returns
+    strided, overlapped (slab:2)} variants.  Returns
     ``(profiles, violations)`` where ``profiles`` maps
     ``"backend/schedule/layer/variant"`` to a ``PlanProfile``.  ``pairs``
     restricts the sweep to a subset of (backend, schedule) pairs — the
@@ -979,15 +969,10 @@ def sweep(*, batch: int = 4, limit: Optional[int] = None,
             if pipeline is None or plan_mod._direct_only(
                     k_shape[2], k_shape[3], 16, 2) is None:
                 variants.append(("stride2", {"stride": 2}, False))
-            if pipeline is not None:
-                # the full-spectrum twin is a legal plan in its own right
-                # — certify it directly, not only as a ratio baseline
-                variants.append(("complex", {"spectrum": "complex"}, False))
-                if needs_mesh:
-                    # overlapped sub-slab execution: slab-scaled collective
-                    # counts + bytes parity vs the sequential twin
-                    variants.append(("overlap", {"overlap": "slab:2"},
-                                     False))
+            if pipeline is not None and needs_mesh:
+                # overlapped sub-slab execution: slab-scaled collective
+                # counts + bytes parity vs the sequential twin
+                variants.append(("overlap", {"overlap": "slab:2"}, False))
             for variant, extra, as_prepared in variants:
                 key = f"{backend}/{schedule}/{name}/{variant}"
                 plan = plan_conv(x_shape, k_shape, **base, **extra)

@@ -76,7 +76,7 @@ def _transposed_plan(plan):
         compute_dtype=plan.compute_dtype, data_axis=plan.data_axis,
         model_axis=plan.model_axis,
         replicate_kernel_transform=plan.replicate_kernel_transform,
-        spectrum=plan.spectrum, overlap=plan.overlap)
+        overlap=plan.overlap)
 
 
 def _dx_via_transposed_plan(plan, k, dz):

@@ -12,13 +12,13 @@ or, threaded through the planner:
 
     plan = plan_conv(x_shape, k_shape, padding=1, backend="tuned")
 
-``tune`` times every candidate (backend, schedule, frequency-layout
-``spectrum``, sub-slab ``overlap``, cgemm ``bm/bn/bk``, ``dft_tile``
-``dft_bt``) configuration on the actual device — warmup then
-median-of-k, under a wall-clock budget — and persists the winner in a JSON
-tuning cache so the tuning cost is paid once per machine.  Cache entries are
-keyed by the spec signature + device kind + jax version: a new device or a
-jax upgrade invalidates naturally (old keys simply never match).
+``tune`` times every candidate (backend, schedule, sub-slab ``overlap``,
+cgemm ``bm/bn/bk``, ``dft_tile`` ``dft_bt``) configuration on the actual
+device — warmup then median-of-k, under a wall-clock budget — and persists
+the winner in a JSON tuning cache so the tuning cost is paid once per
+machine.  Cache entries are keyed by the spec signature + device kind +
+jax version: a new device or a jax upgrade invalidates naturally (old
+keys simply never match).
 
 Candidates are timed through the real planner with a representative
 bias+relu epilogue, so the ``fft-pallas``/``local`` fused ``dft_tile``
@@ -63,7 +63,7 @@ from repro.core.conv_spec import ConvSpec
 from repro.conv.plan import _build_spec as _make_spec
 from repro.conv.plan import _normalize_padding
 
-CACHE_VERSION = 3
+CACHE_VERSION = 4
 
 _DEFAULT_CACHE = os.path.join("~", ".cache", "repro_autotune.json")
 _DEFAULT_BUDGET_MS = 2000.0
@@ -87,7 +87,6 @@ class TunedConfig:
     bn: Optional[int] = None
     bk: Optional[int] = None
     dft_bt: Optional[int] = None       # dft_tile tile-batch block
-    spectrum: str = "real"             # frequency layout (FFT pipelines)
     overlap: str = "off"               # sub-slab comm/compute overlap
     us_per_call: Optional[float] = None
     source: str = "measured"
@@ -254,15 +253,15 @@ def spec_signature(x_shape, k_shape, *, padding=(0, 0), delta: int = 16,
                    three_m: bool = True, compute_dtype=None,
                    data_axis: str = "data", model_axis: str = "model",
                    replicate_kernel_transform: bool = False,
-                   spectrum: str = "auto", overlap: str = "off",
+                   overlap: str = "off",
                    bm=None, bn=None, bk=None, dft_bt=None) -> str:
     """Device-independent part of the cache key: the problem + the
     constraints the caller put on the tuner (requested schedule, mesh,
-    precision, kernel-transform placement, requested spectrum, pinned
-    blocks).  Two calls that could legally get different winners must get
-    different signatures — a pin-constrained sweep must never answer for
-    an unconstrained one.  Unit stride adds nothing to the signature, so
-    persisted unit-stride entries keep their keys."""
+    precision, kernel-transform placement, pinned blocks).  Two calls that
+    could legally get different winners must get different signatures — a
+    pin-constrained sweep must never answer for an unconstrained one.  Unit
+    stride adds nothing to the signature, so persisted unit-stride entries
+    keep their keys."""
     pad = _normalize_padding(padding)
     strided = f"|stride={int(stride)}" if int(stride) != 1 else ""
     return (f"v{CACHE_VERSION}"
@@ -272,7 +271,7 @@ def spec_signature(x_shape, k_shape, *, padding=(0, 0), delta: int = 16,
             f"|dtype={_dtype_name(compute_dtype)}"
             f"|axes={data_axis},{model_axis}"
             f"|rkt={int(bool(replicate_kernel_transform))}"
-            f"|spec={spectrum}|ov={overlap}"
+            f"|ov={overlap}"
             f"|pins={bm},{bn},{bk},{dft_bt}")
 
 
@@ -314,18 +313,11 @@ def _merge_pins(cand: TunedConfig, bm, bn, bk, dft_bt) -> TunedConfig:
 
 
 def candidates(spec: ConvSpec, *, schedule: str = "auto", mesh=None,
-               spectrum: str = "auto",
                overlap: str = "off",
                bm=None, bn=None, bk=None, dft_bt=None) -> list:
     """Enumerate the tuning space, cost-model pick first (so a clamped
     budget still measures the sane default), Pallas configs last (interpret
     mode on CPU makes them the most expensive to time).
-
-    ``spectrum="auto"`` adds a real-vs-complex frequency-layout axis for
-    the FFT backends (the compact half-spectrum wins on bandwidth-bound
-    geometries, the full spectrum can win when the packing gather
-    dominates); ``direct`` has no spectrum and is tuned as ``"real"``
-    only.  Pinning ``spectrum`` collapses the axis.
 
     ``overlap="auto"`` adds the sub-slab comm/compute-overlap axis
     (``off``/``slab:2``/``slab:4``) for the sharded FFT schedules; local
@@ -337,13 +329,12 @@ def candidates(spec: ConvSpec, *, schedule: str = "auto", mesh=None,
     ``direct`` on ``local``."""
     from repro.conv import plan
     if plan._direct_only(spec.kh, spec.kw, spec.delta, spec.stride):
-        return [_merge_pins(TunedConfig("direct", "local", spectrum="real"),
+        return [_merge_pins(TunedConfig("direct", "local"),
                             bm, bn, bk, dft_bt)]
     if schedule != "auto":
         scheds = [schedule]
     else:
         scheds = ["nfft", "wfft"] if mesh is not None else ["local"]
-    spectra = ["real", "complex"] if spectrum == "auto" else [spectrum]
     out = []
     for sched in scheds:
         local = sched == "local"
@@ -357,38 +348,25 @@ def candidates(spec: ConvSpec, *, schedule: str = "auto", mesh=None,
                     else ["fft-xla", "fft-pallas"])
         for be in backends:
             if be == "direct":
-                # the direct pipeline never builds a spectrum; a pinned
-                # spectrum="complex" sweep excludes it (plan_conv rejects
-                # the pair)
-                if "real" in spectra:
-                    out.append(TunedConfig(be, sched, spectrum="real"))
+                out.append(TunedConfig(be, sched))
                 continue
-            for spc in spectra:
-                for ov in ovs:
-                    if be != "fft-pallas":
-                        out.append(TunedConfig(be, sched, spectrum=spc,
-                                               overlap=ov))
-                        continue
-                    if spc != "real" or ov != "off":
-                        # complex Pallas takes the composed stage-4 path
-                        # (no fused tail) and overlapped Pallas re-pins
-                        # blocks per sub-slab — time only the
-                        # default-block point
-                        out.append(TunedConfig(be, sched, spectrum=spc,
-                                               overlap=ov))
-                        continue
-                    bts = [None, 64] if local else [None]
-                    for blocks in _block_candidates(spec):
-                        for bt in bts:
-                            out.append(TunedConfig(be, sched, *blocks,
-                                                   dft_bt=bt, spectrum=spc,
-                                                   overlap=ov))
+            for ov in ovs:
+                if be != "fft-pallas" or ov != "off":
+                    # overlapped Pallas re-pins blocks per sub-slab — time
+                    # only the default-block point
+                    out.append(TunedConfig(be, sched, overlap=ov))
+                    continue
+                bts = [None, 64] if local else [None]
+                for blocks in _block_candidates(spec):
+                    for bt in bts:
+                        out.append(TunedConfig(be, sched, *blocks,
+                                               dft_bt=bt, overlap=ov))
     out = [_merge_pins(c, bm, bn, bk, dft_bt) for c in out]
     # dedupe (pins can collapse block variants) preserving order
     seen, uniq = set(), []
     for c in out:
         key = (c.backend, c.schedule, c.bm, c.bn, c.bk, c.dft_bt,
-               c.spectrum, c.overlap)
+               c.overlap)
         if key not in seen:
             seen.add(key)
             uniq.append(c)
@@ -396,7 +374,6 @@ def candidates(spec: ConvSpec, *, schedule: str = "auto", mesh=None,
     # pick is always a single candidate), Pallas variants last
     pick = _cost_model_pick(spec, scheds[0])
     uniq.sort(key=lambda c: 0 if ((c.backend, c.schedule) == pick
-                                  and c.spectrum == "real"
                                   and c.overlap == "off")
               else 1 if c.backend != "fft-pallas" else 2)
     return uniq
@@ -441,8 +418,7 @@ def _measure_candidate(cand: TunedConfig, x_shape, k_shape, *, padding,
                      stride=stride, backend=cand.backend,
                      schedule=cand.schedule, mesh=mesh, three_m=three_m,
                      bm=cand.bm, bn=cand.bn,
-                     bk=cand.bk, dft_bt=cand.dft_bt,
-                     spectrum=cand.spectrum, overlap=cand.overlap,
+                     bk=cand.bk, dft_bt=cand.dft_bt, overlap=cand.overlap,
                      compute_dtype=compute_dtype, data_axis=data_axis,
                      model_axis=model_axis,
                      replicate_kernel_transform=replicate_kernel_transform,
@@ -460,16 +436,14 @@ def _measure_candidate(cand: TunedConfig, x_shape, k_shape, *, padding,
 # --------------------------------------------------------------------------
 
 def _cost_model_config(spec: ConvSpec, schedule: str, mesh,
-                       spectrum, overlap, bm, bn, bk, dft_bt) -> TunedConfig:
+                       overlap, bm, bn, bk, dft_bt) -> TunedConfig:
     if schedule == "auto":
         schedule = "nfft" if mesh is not None else "local"
     backend, _ = _cost_model_pick(spec, schedule)
-    if spectrum == "auto" or backend == "direct":
-        spectrum = "real"               # compact layout is the engine default
     if overlap == "auto":
         overlap = "off"                 # the cost model never bets on overlap
     return TunedConfig(backend, schedule, bm=bm, bn=bn, bk=bk,
-                       dft_bt=dft_bt, spectrum=spectrum, overlap=overlap,
+                       dft_bt=dft_bt, overlap=overlap,
                        us_per_call=None, source="cost-model")
 
 
@@ -478,7 +452,7 @@ def tune(spec, k_shape=None, *, padding=None, delta: Optional[int] = None,
          three_m: bool = True, compute_dtype=None, data_axis: str = "data",
          model_axis: str = "model",
          replicate_kernel_transform: bool = False,
-         spectrum: str = "auto", overlap: str = "off",
+         overlap: str = "off",
          bm=None, bn=None, bk=None, dft_bt=None,
          budget: Optional[float] = None,
          reps: Optional[int] = None) -> TunedConfig:
@@ -523,7 +497,7 @@ def tune(spec, k_shape=None, *, padding=None, delta: Optional[int] = None,
                       compute_dtype=compute_dtype, data_axis=data_axis,
                       model_axis=model_axis,
                       replicate_kernel_transform=replicate_kernel_transform,
-                      spectrum=spectrum, overlap=overlap,
+                      overlap=overlap,
                       bm=bm, bn=bn, bk=bk, dft_bt=dft_bt)
     key = cache_key(x_shape, k_shape, **key_kwargs)
     store = _store()
@@ -538,12 +512,12 @@ def tune(spec, k_shape=None, *, padding=None, delta: Optional[int] = None,
         with _lock:
             _fallbacks += 1
         return _cost_model_config(spec, schedule, mesh,
-                                  spectrum, overlap, bm, bn, bk, dft_bt)
+                                  overlap, bm, bn, bk, dft_bt)
     with _lock:
         _misses += 1
 
     cands = candidates(spec, schedule=schedule, mesh=mesh,
-                       spectrum=spectrum, overlap=overlap,
+                       overlap=overlap,
                        bm=bm, bn=bn, bk=bk, dft_bt=dft_bt)
     budget = budget_ms() if budget is None else float(budget)
     reps = _env_reps() if reps is None else max(1, int(reps))

@@ -46,38 +46,17 @@ def _pallas_cgemm_fn(plan):
                              bm=plan.bm, bn=plan.bn, bk=plan.bk)
 
 
-def _pallas_fused_inverse(Zr, Zi, spec, epilogue, bias, *, bt=None):
-    """Stage 4 through the fused dft_tile kernel: inverse DFT + bias +
-    activation in one VMEM-resident tail.
+def _pallas_fused_inverse_real(Zr, Zi, spec, epilogue, bias, *, bt=None):
+    """Stage 4 through the fused ``dft_tile`` kernel: compact-spectrum
+    scatter + inverse DFT + bias + activation in one VMEM-resident pass.
 
     The activation runs on whole tiles before the overlap-save crop; the
     crop only *selects* elements, so elementwise-before-crop equals
     crop-then-elementwise on everything kept.  ``bt`` is the plan's
     ``dft_bt`` tile-batch block override (autotuned or user-pinned).
     """
-    from repro.kernels.dft_tile import tile_ifft_epilogue_pallas
-    Zrt = F.z_to_tiles(Zr, spec)            # (B, C', X, Dl, d, dh)
-    Zit = F.z_to_tiles(Zi, spec)
-    B, Co, X, Dl = Zrt.shape[:4]
-    n = B * Co * X * Dl
-    d, dh = spec.delta, spec.delta_h
-    b = bias if bias is not None else jnp.zeros((Co,), Zr.dtype)
-    # one bias scalar per tile: broadcast over (B, ., X, Dl) tile indices
-    b_tile = jnp.broadcast_to(b.astype(Zr.dtype)[None, :, None, None],
-                              (B, Co, X, Dl)).reshape(n)
-    y = tile_ifft_epilogue_pallas(Zrt.reshape(n, d, dh),
-                                  Zit.reshape(n, d, dh), b_tile,
-                                  activation=epilogue.activation,
-                                  delta=d, bt=bt)
-    return F.assemble_output_tiles(y.reshape(B, Co, X, Dl, d, d), spec)
-
-
-def _pallas_fused_inverse_real(Zr, Zi, spec, epilogue, bias, *, bt=None):
-    """The ``spectrum="real"`` fused stage-4 tail: compact-layout scatter +
-    inverse DFT + bias + activation in one ``dft_tile`` kernel pass."""
     from repro.kernels.dft_tile import tile_irfft_epilogue_pallas
-    from repro.core.dft import num_freq_real
-    P = num_freq_real(spec.delta)
+    P = spec.freq_points()
     Zrt = F.z_to_flat_tiles(Zr, spec, P)    # (B, C', X, Dl, P)
     Zit = F.z_to_flat_tiles(Zi, spec, P)
     B, Co, X, Dl = Zrt.shape[:4]
@@ -110,13 +89,9 @@ def _fft_xla_pipeline(plan):
 
 
 def _fft_pallas_pipeline(plan):
-    inverse_fn = None
-    if plan.schedule == "local" and plan.spectrum == "real":
-        # fused dft_tile tail for the compact layout; the full-spectrum
-        # twin takes the composed stage-4 path (it is the measurement
-        # baseline, not the fast path)
-        inverse_fn = functools.partial(_pallas_fused_inverse_real,
-                                       bt=plan.dft_bt)
+    inverse_fn = functools.partial(_pallas_fused_inverse_real,
+                                   bt=plan.dft_bt) \
+        if plan.schedule == "local" else None
     return stages.pipeline_for(plan.schedule,
                                cgemm_fn=_pallas_cgemm_fn(plan),
                                inverse_fn=inverse_fn)

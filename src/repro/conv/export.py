@@ -54,15 +54,14 @@ from typing import Any, Mapping, Optional
 
 from repro.conv.epilogue import Epilogue
 
-ARTIFACT_VERSION = 1
+ARTIFACT_VERSION = 2
 
 # The PlanProfile facts a fingerprint certifies: everything structural
-# about the schedule (backend/schedule/collectives/stage ops/spectrum/
-# overlap/epilogue/precision), nothing measured or byte-counted.
+# about the schedule (backend/schedule/collectives/stage ops/overlap/
+# epilogue/precision), nothing measured or byte-counted.
 FINGERPRINT_FIELDS = (
     "backend", "schedule", "prepared", "collectives", "stage_counts",
-    "spectrum", "overlap", "num_slabs", "epilogue", "compute_dtype",
-    "cgemm_dtypes",
+    "overlap", "num_slabs", "epilogue", "compute_dtype", "cgemm_dtypes",
 )
 
 
@@ -140,7 +139,6 @@ def plan_config(plan) -> dict:
         "epilogue": {"bias": plan.epilogue.bias,
                      "activation": plan.epilogue.activation,
                      "residual": plan.epilogue.residual},
-        "spectrum": plan.spectrum,
         "overlap": plan.overlap,
     }
 
@@ -163,7 +161,7 @@ def rebuild_plan(cfg: dict):
         data_axis=cfg["data_axis"], model_axis=cfg["model_axis"],
         replicate_kernel_transform=cfg["replicate_kernel_transform"],
         epilogue=Epilogue(**cfg["epilogue"]),
-        spectrum=cfg["spectrum"], overlap=cfg["overlap"])
+        overlap=cfg["overlap"])
 
 
 # --------------------------------------------------------------------------

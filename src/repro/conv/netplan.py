@@ -41,7 +41,6 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import warnings
 from typing import Any, Callable, Mapping, Optional, Sequence, Union
 
 import jax
@@ -196,15 +195,6 @@ class NetworkPlan:
             for name, plan in self.plans.items())
         return PreparedNetwork(layers=layers,
                                weights_version=weights_version)
-
-    def prepare_all(self, params: Mapping[str, Any], *,
-                    weights_version=None) -> PreparedNetwork:
-        """Deprecated spelling of ``NetworkPlan.prepare``."""
-        warnings.warn(
-            "NetworkPlan.prepare_all is deprecated; use "
-            "NetworkPlan.prepare(params, weights_version=...)",
-            DeprecationWarning, stacklevel=2)
-        return self.prepare(params, weights_version=weights_version)
 
     def export(self, path: str, params: Optional[Mapping[str, Any]] = None,
                *, weights_version=None) -> str:
@@ -397,7 +387,6 @@ def plan_network(layers: Union[Sequence[NetworkConv], Callable], *,
                  three_m: bool = True, compute_dtype=None,
                  data_axis: str = "data", model_axis: str = "model",
                  replicate_kernel_transform: bool = False,
-                 spectrum: str = "auto",
                  overlap: str = "off"):
     """Resolve every conv layer of a model in one planning pass.
 
@@ -422,7 +411,7 @@ def plan_network(layers: Union[Sequence[NetworkConv], Callable], *,
                   three_m=three_m, compute_dtype=compute_dtype,
                   data_axis=data_axis, model_axis=model_axis,
                   replicate_kernel_transform=replicate_kernel_transform,
-                  spectrum=spectrum, overlap=overlap)
+                  overlap=overlap)
     if buckets is not None:
         if not callable(layers):
             raise TypeError(
@@ -469,39 +458,3 @@ def _bucket_report(nets: Mapping[Any, NetworkPlan]) -> dict:
                          else 1.0),
         "buckets": per_bucket,
     }
-
-
-# --------------------------------------------------------------------------
-# Deprecated bucket-helper shims (pre-BucketedNetworkPlan spellings)
-# --------------------------------------------------------------------------
-
-def plan_network_buckets(make_layers, batches: Sequence[int],
-                         **plan_kwargs) -> BucketedNetworkPlan:
-    """Deprecated: use ``plan_network(make_layers, buckets=batches)``."""
-    warnings.warn(
-        "plan_network_buckets is deprecated; use "
-        "plan_network(make_layers, buckets=batches)",
-        DeprecationWarning, stacklevel=2)
-    return plan_network(make_layers, buckets=batches, **plan_kwargs)
-
-
-def prepare_network_buckets(nets: Mapping[int, NetworkPlan],
-                            params: Mapping[str, Any], *,
-                            weights_version=None
-                            ) -> "collections.OrderedDict":
-    """Deprecated: use ``BucketedNetworkPlan.prepare``."""
-    warnings.warn(
-        "prepare_network_buckets is deprecated; use "
-        "BucketedNetworkPlan.prepare(params, weights_version=...)",
-        DeprecationWarning, stacklevel=2)
-    return collections.OrderedDict(
-        (b, net.prepare(params, weights_version=weights_version))
-        for b, net in nets.items())
-
-
-def bucket_report(nets: Mapping[Any, NetworkPlan]) -> dict:
-    """Deprecated: use ``BucketedNetworkPlan.report``."""
-    warnings.warn(
-        "bucket_report is deprecated; use BucketedNetworkPlan.report()",
-        DeprecationWarning, stacklevel=2)
-    return _bucket_report(nets)

@@ -72,7 +72,6 @@ class ConvPlan:
     model_axis: str = "model"
     replicate_kernel_transform: bool = False
     epilogue: Epilogue = Epilogue()    # fused elementwise tail (stage 4)
-    spectrum: str = "real"             # "real" (compact Hermitian) | "complex"
     overlap: str = "off"               # "off" | "slab:<k>" sub-slab overlap
 
     @property
@@ -217,8 +216,7 @@ class ConvPlan:
         """Cost-model FLOPs of the planned path (for rooflines)."""
         if self.backend == "direct":
             return self.spec.direct_flops()
-        return sum(self.spec.stage_flops(self.spectrum,
-                                         three_m=self.three_m).values())
+        return sum(self.spec.stage_flops(three_m=self.three_m).values())
 
     def describe(self) -> str:
         s = self.spec
@@ -226,7 +224,7 @@ class ConvPlan:
             f"ConvPlan {self.x_shape} * {self.k_shape} -> {self.out_shape}",
             f"  backend={self.backend} schedule={self.schedule} "
             f"stride={s.stride} three_m={self.three_m} delta={s.delta} "
-            f"spectrum={self.spectrum} epilogue={self.epilogue.describe()}",
+            f"epilogue={self.epilogue.describe()}",
             "  predicted ms: " + ", ".join(
                 f"{be} {t * 1e3:.3f}" for be, t in predicted_s(s).items()),
         ]
@@ -437,8 +435,8 @@ def predicted_s(spec: ConvSpec) -> dict:
     CGEMM C and Cout."""
     direct = _stage_s(spec.direct_flops(), spec.direct_bytes(),
                       _fill(spec.C * spec.kh * spec.kw) * _fill(spec.Cout))
-    f, b = spec.stage_flops("real"), spec.stage_bytes("real")
-    P = spec.freq_points("real")
+    f, b = spec.stage_flops(), spec.stage_bytes()
+    P = spec.freq_points()
     fft = sum(_stage_s(f[s], b[s] * _FFT_BYTES_SCALE, fill) for s, fill in (
         ("input_transform", _fill(P)),
         ("cgemm", _fill(spec.C) * _fill(spec.Cout)),
@@ -508,14 +506,8 @@ def _resolve_overlap(overlap, spec, sched, be, backend, schedule, mesh,
 def _resolve(x_shape, k_shape, padding, delta, backend, schedule, mesh,
              three_m, bm, bn, bk, dft_bt, compute_dtype, data_axis,
              model_axis, replicate_kernel_transform, epilogue,
-             spectrum, overlap="off", stride=1) -> ConvPlan:
+             overlap="off", stride=1) -> ConvPlan:
     _, _, kh, kw = k_shape
-    if spectrum == "auto":
-        spectrum = "real"    # compact Hermitian layout is the default path
-    if spectrum not in ("real", "complex"):
-        raise ValueError(
-            f"unknown spectrum {spectrum!r} (choose 'real', 'complex', or "
-            "'auto')")
     # Kernels larger than the FFT tile and strides rule out the FFT
     # backends but are fine for direct conv: _build_spec widens the
     # (then-unused) tile so the spec validates, and auto resolves to
@@ -564,10 +556,6 @@ def _resolve(x_shape, k_shape, padding, delta, backend, schedule, mesh,
             f"backend {backend!r} cannot fuse an epilogue "
             f"({epilogue.describe()}); register it with "
             "supports_epilogue=True or use a stage-pipeline backend")
-    if spectrum == "complex" and be.pipeline_factory is None:
-        raise ValueError(
-            f"spectrum='complex' (the full-spectrum twin) only applies to "
-            f"the FFT stage pipelines; backend {backend!r} has no spectrum")
 
     # -- overlap (comm/compute-overlapped sub-slab execution) ---------------
     overlap = _resolve_overlap(overlap, spec, sched, be, backend, schedule,
@@ -594,7 +582,7 @@ def _resolve(x_shape, k_shape, padding, delta, backend, schedule, mesh,
                     dft_bt=dft_bt, compute_dtype=compute_dtype, mesh=mesh,
                     data_axis=data_axis, model_axis=model_axis,
                     replicate_kernel_transform=replicate_kernel_transform,
-                    epilogue=epilogue, spectrum=spectrum, overlap=overlap)
+                    epilogue=epilogue, overlap=overlap)
 
 
 def plan_conv(spec, k_shape=None, *, padding=None, delta: Optional[int] = None,
@@ -604,7 +592,6 @@ def plan_conv(spec, k_shape=None, *, padding=None, delta: Optional[int] = None,
               model_axis: str = "model",
               replicate_kernel_transform: bool = False,
               epilogue: Optional[Epilogue] = None,
-              spectrum: str = "auto",
               overlap: str = "off",
               stride: Optional[int] = None,
               cache: bool = True) -> ConvPlan:
@@ -649,13 +636,6 @@ def plan_conv(spec, k_shape=None, *, padding=None, delta: Optional[int] = None,
         residual add) on the local output slab, before the output dtype
         cast — zero extra collectives, zero extra stage ops.  The operand
         values are execution arguments: ``plan(x, k, bias=b, residual=r)``.
-      spectrum: frequency-domain layout for the FFT pipelines.  ``"real"``
-        (the ``"auto"`` default) flows the compact Hermitian half-spectrum
-        (~0.51x the frequency points at delta=16) through every stage —
-        the nfft all-to-alls and wfft psum move roughly half the bytes;
-        ``"complex"`` is the full-spectrum twin (measurement baseline).
-        With ``backend="tuned"`` and ``spectrum="auto"`` the tuner picks
-        per geometry.
       overlap: comm/compute-overlapped execution for the sharded
         schedules.  ``"slab:<k>"`` splits the per-rank batch into k
         sub-slabs inside the shard_map body and double-buffers, so the
@@ -715,12 +695,10 @@ def plan_conv(spec, k_shape=None, *, padding=None, delta: Optional[int] = None,
                 compute_dtype=compute_dtype, data_axis=data_axis,
                 model_axis=model_axis,
                 replicate_kernel_transform=replicate_kernel_transform,
-                spectrum=spectrum, overlap=overlap)
+                overlap=overlap)
             backend = tuned.backend
             if schedule == "auto":
                 schedule = tuned.schedule
-            if spectrum == "auto":
-                spectrum = tuned.spectrum
             if overlap == "auto":
                 overlap = tuned.overlap
             # explicit caller overrides beat tuned blocks
@@ -728,12 +706,10 @@ def plan_conv(spec, k_shape=None, *, padding=None, delta: Optional[int] = None,
             bn = bn if bn is not None else tuned.bn
             bk = bk if bk is not None else tuned.bk
             dft_bt = dft_bt if dft_bt is not None else tuned.dft_bt
-    if spectrum == "auto":
-        spectrum = "real"    # deterministic default — share the cache entry
     key = (x_shape, k_shape, padding, delta, backend, schedule,
            _mesh_cache_key(mesh), three_m, bm, bn, bk, dft_bt,
            compute_dtype, data_axis, model_axis,
-           replicate_kernel_transform, epilogue, spectrum, overlap, stride)
+           replicate_kernel_transform, epilogue, overlap, stride)
     if cache:
         with _cache_lock:
             plan = _plan_cache.get(key)
@@ -744,7 +720,7 @@ def plan_conv(spec, k_shape=None, *, padding=None, delta: Optional[int] = None,
     plan = _resolve(x_shape, k_shape, padding, delta, backend, schedule,
                     mesh, three_m, bm, bn, bk, dft_bt, compute_dtype,
                     data_axis, model_axis, replicate_kernel_transform,
-                    epilogue, spectrum, overlap, stride)
+                    epilogue, overlap, stride)
     if cache:
         with _cache_lock:
             _cache_misses += 1

@@ -33,11 +33,10 @@ Every pipeline exposes the prepare/execute split:
                          a prepared ``G``;
   ``full(plan, x, k)``   the one-shot path: stage 2 inline.
 
-Every stage op takes a ``spectrum`` layout argument (see
-``repro.core.fftconv``): ``"real"`` flows the compact Hermitian
-half-spectrum (~0.51x the frequency points) through the whole graph —
-the nfft boundary all-to-alls and the wfft hot psum pair move roughly
-half the bytes of the ``"complex"`` full-spectrum twin.
+The frequency axis is the compact Hermitian half-spectrum of
+``spec.freq_points()`` points (see ``repro.core.fftconv``) through the
+whole graph: stage 1's output, G, Z, the nfft boundary all-to-alls and
+the wfft hot psum pair all carry it.
 
 Each forward stage op (1, 3 and 4) also opens a ``jax.named_scope`` of
 its counter's name — ``input_transform``, ``cgemm``, ``output_inverse``
@@ -56,8 +55,6 @@ context manager::
 Traces also record dtype facts as ``("cgemm_dtype", <dtype>)`` tuple keys
 alongside the plain string op counts — the static analyzer reads these to
 certify that ``compute_dtype`` actually reached the hot stage — and, from
-stages 1 and 4, ``("transform_form", "folded" | "separable")``: how the
-tile DFT was applied (``repro.core.fftconv.transform_form``), and, from
 stage 1 or a ``direct`` layer whose spec has ``stride > 1``,
 ``("stride", s)``.
 """
@@ -137,16 +134,15 @@ def count_stride(stride: int) -> None:
         _count(("stride", stride))
 
 
-def stage_input_transform(x, spec: ConvSpec, spectrum: str = "rect"):
-    _count(("transform_form", F.transform_form(spectrum)))
+def stage_input_transform(x, spec: ConvSpec):
     count_stride(spec.stride)
     with _stage("input_transform"):
-        return F.input_transform(x, spec, spectrum=spectrum)
+        return F.input_transform(x, spec)
 
 
-def stage_kernel_transform(k, spec: ConvSpec, spectrum: str = "rect"):
+def stage_kernel_transform(k, spec: ConvSpec):
     _count("kernel_transform")
-    return F.kernel_transform(k, spec, spectrum=spectrum)
+    return F.kernel_transform(k, spec)
 
 
 def stage_cgemm(Dr, Di, Gr, Gi, *, three_m: bool, cgemm_fn=None):
@@ -165,23 +161,21 @@ def stage_cgemm(Dr, Di, Gr, Gi, *, three_m: bool, cgemm_fn=None):
 
 
 def stage_output_inverse(Zr, Zi, spec: ConvSpec, *, epilogue: Epilogue = None,
-                         bias=None, residual=None, inverse_fn=None,
-                         spectrum: str = "rect"):
+                         bias=None, residual=None, inverse_fn=None):
     """Stage 4 with the fused elementwise epilogue.
 
     The epilogue rides inside this single stage op (the counter increments
     once, fused or not).  ``inverse_fn`` is a backend-supplied fused
     inverse+epilogue kernel ``(Zr, Zi, spec, epilogue, bias) -> y`` (the
-    Pallas ``dft_tile`` tail) matched to the plan's spectrum layout; it
-    cannot fold a residual — the residual lives in output layout, not tile
-    layout — so residual epilogues fall back to the composed path.
+    Pallas ``dft_tile`` tail); it cannot fold a residual — the residual
+    lives in output layout, not tile layout — so residual epilogues fall
+    back to the composed path.
     """
-    _count(("transform_form", F.transform_form(spectrum)))
     with _stage("output_inverse"):
         if (inverse_fn is not None and epilogue is not None
                 and not epilogue.is_noop and not epilogue.residual):
             return inverse_fn(Zr, Zi, spec, epilogue, bias)
-        y = F.output_inverse(Zr, Zi, spec, spectrum=spectrum)
+        y = F.output_inverse(Zr, Zi, spec)
         return apply_epilogue(y, epilogue, bias=bias, residual=residual)
 
 
@@ -318,11 +312,11 @@ class LocalPipeline:
         self.inverse_fn = inverse_fn
 
     def prepare(self, plan, k):
-        return stage_kernel_transform(k, plan.spec, plan.spectrum)
+        return stage_kernel_transform(k, plan.spec)
 
     def execute(self, plan, x, G, bias=None, residual=None):
         spec = plan.spec
-        Dr, Di = stage_input_transform(x, spec, plan.spectrum)
+        Dr, Di = stage_input_transform(x, spec)
         Gr, Gi = G
         Dr, Di = _maybe_cast((Dr, Di), plan.compute_dtype)
         Gr, Gi = _maybe_cast((Gr, Gi), plan.compute_dtype)
@@ -331,8 +325,7 @@ class LocalPipeline:
         Zr, Zi = Zr.astype(jnp.float32), Zi.astype(jnp.float32)
         y = stage_output_inverse(Zr, Zi, spec, epilogue=plan.epilogue,
                                  bias=bias, residual=residual,
-                                 inverse_fn=self.inverse_fn,
-                                 spectrum=plan.spectrum)
+                                 inverse_fn=self.inverse_fn)
         return y.astype(x.dtype)
 
     def full(self, plan, x, k, bias=None, residual=None):
@@ -409,7 +402,7 @@ class NfftPipeline:
     def _stage1_and_boundary1(self, x, plan, spec, slab=False):
         b_loc, c_loc = x.shape[0], x.shape[1]
         sp1 = _local_spec(spec, b_loc, c_loc, spec.Cout)
-        Dr, Di = stage_input_transform(x, sp1, plan.spectrum)
+        Dr, Di = stage_input_transform(x, sp1)
         # The tiled all-to-all splits the P axis N ways: pad the frequency
         # list up to a model-axis multiple ONCE here (padded rows are zero,
         # flow inertly through the CGEMM, and stage 4 slices them off).
@@ -429,7 +422,7 @@ class NfftPipeline:
         if plan.replicate_kernel_transform:
             # Stage 2': full kernel transform on every rank, local P-slab
             # slice — removes boundary a2a #2 (beyond-paper optimization).
-            Gr, Gi = stage_kernel_transform(k, sp2, plan.spectrum)
+            Gr, Gi = stage_kernel_transform(k, sp2)
             Gr, Gi = _pad_axis(Gr, 0, n_model), _pad_axis(Gi, 0, n_model)
             p_loc = Gr.shape[0] // n_model
             idx = jax.lax.axis_index(plan.model_axis) * p_loc
@@ -437,7 +430,7 @@ class NfftPipeline:
             Gi = jax.lax.dynamic_slice_in_dim(Gi, idx, p_loc, axis=0)
             return Gr, Gi
         # Stage 2: transform the local C'_loc kernels -> G (P, C, C'_loc)
-        Gr, Gi = stage_kernel_transform(k, sp2, plan.spectrum)
+        Gr, Gi = stage_kernel_transform(k, sp2)
         Gr, Gi = _pad_axis(Gr, 0, n_model), _pad_axis(Gi, 0, n_model)
         # Boundary a2a #2: (P, C, C'_loc) -> (P/N, C, C')
         return _boundary_a2a(Gr, Gi, plan.model_axis, 0, 2)
@@ -461,8 +454,7 @@ class NfftPipeline:
         # zero collectives), before the output dtype cast.
         sp4 = _local_spec(spec, b_loc, c_full, spec.Cout // n_model)
         return stage_output_inverse(Zr, Zi, sp4, epilogue=plan.epilogue,
-                                    bias=bias, residual=residual,
-                                    spectrum=plan.spectrum)
+                                    bias=bias, residual=residual)
 
     # ---- global entry points ----------------------------------------------
 
@@ -477,7 +469,7 @@ class NfftPipeline:
         spec = padded_sharded_spec(plan)
         n_model = plan.mesh.shape[plan.model_axis]
         kp = _pad_axis(_pad_axis(k, 0, n_model), 1, n_model)
-        Gr, Gi = stage_kernel_transform(kp, spec, plan.spectrum)
+        Gr, Gi = stage_kernel_transform(kp, spec)
         return _place((_pad_axis(Gr, 0, n_model), _pad_axis(Gi, 0, n_model)),
                       plan, P(plan.model_axis, None, None))
 
@@ -571,7 +563,7 @@ class WfftPipeline:
         """Stage 1 + the partial (C-sharded contraction) cgemm for one
         batch slab; G enters already cast to compute_dtype."""
         sp1 = _local_spec(spec, x.shape[0], x.shape[1], spec.Cout)
-        Dr, Di = stage_input_transform(x, sp1, plan.spectrum)  # (P, M, C_loc)
+        Dr, Di = stage_input_transform(x, sp1)  # (P, M, C_loc)
         Dr, Di = _maybe_cast((Dr, Di), plan.compute_dtype)
         Zr, Zi = stage_cgemm(Dr, Di, Gr, Gi, three_m=plan.three_m,
                              cgemm_fn=self.cgemm_fn)  # partial sums, f32 acc
@@ -599,13 +591,12 @@ class WfftPipeline:
         Zi = jax.lax.dynamic_slice_in_dim(Zi, idx * co_loc, co_loc, axis=2)
         sp4 = _local_spec(spec, x.shape[0], x.shape[1], co_loc)
         return stage_output_inverse(Zr, Zi, sp4, epilogue=plan.epilogue,
-                                    bias=bias, residual=residual,
-                                    spectrum=plan.spectrum)
+                                    bias=bias, residual=residual)
 
     def _body_full(self, x, k, *ep_args, plan, spec, n_model):
         """k: (C'_full, C_loc, kh, kw) — stage 2 inline on the local slab."""
         sp2 = _local_spec(spec, x.shape[0], k.shape[1], k.shape[0])
-        Gr, Gi = stage_kernel_transform(k, sp2, plan.spectrum)
+        Gr, Gi = stage_kernel_transform(k, sp2)
         return self._body(x, Gr, Gi, *ep_args, plan=plan, spec=spec,
                           n_model=n_model)
 
@@ -615,7 +606,7 @@ class WfftPipeline:
         spec = padded_sharded_spec(plan)
         n_model = plan.mesh.shape[plan.model_axis]
         kp = _pad_axis(_pad_axis(k, 0, n_model), 1, n_model)
-        return _place(stage_kernel_transform(kp, spec, plan.spectrum), plan,
+        return _place(stage_kernel_transform(kp, spec), plan,
                       P(None, plan.model_axis, None))
 
     def _run(self, plan, x, args, body, extra_in_specs):

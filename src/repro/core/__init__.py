@@ -1,16 +1,15 @@
 """Core FFT-based convolution algorithm (the paper's contribution)."""
 from repro.core.conv_spec import ConvSpec
 from repro.core.fftconv import (
-    fft_conv2d, fft_conv2d_pallas, conv2d_direct, make_spec,
+    conv2d_direct, make_spec,
     input_transform, kernel_transform, output_inverse,
 )
 from repro.core.cgemm import cgemm, cgemm_3m, cgemm_4m
-from repro.core.dft import rfft2_tiles, irfft2_tiles, dft_mats, num_freq
+from repro.core.dft import rfft2_tiles, irfft2_tiles, dft_mats
 
 __all__ = [
-    "ConvSpec", "fft_conv2d", "fft_conv2d_pallas", "conv2d_direct",
-    "make_spec",
+    "ConvSpec", "conv2d_direct", "make_spec",
     "input_transform", "kernel_transform", "output_inverse",
     "cgemm", "cgemm_3m", "cgemm_4m",
-    "rfft2_tiles", "irfft2_tiles", "dft_mats", "num_freq",
+    "rfft2_tiles", "irfft2_tiles", "dft_mats",
 ]

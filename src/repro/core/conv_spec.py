@@ -67,14 +67,6 @@ class ConvSpec:
     def M(self) -> int:                # CGEMM row count: B * X * Delta
         return self.B * self.n_tiles
 
-    @property
-    def delta_h(self) -> int:          # rfft column count
-        return self.delta // 2 + 1
-
-    @property
-    def P(self) -> int:                # stored complex frequency points
-        return self.delta * self.delta_h
-
     # padded input extent covered by the tile grid (>= H + 2*pad)
     @property
     def Hp(self) -> int:
@@ -84,18 +76,13 @@ class ConvSpec:
     def Wp(self) -> int:
         return (self.D - 1) * self.t_w + self.delta
 
-    def freq_points(self, spectrum: str = "rect") -> int:
-        """Stored frequency points for a spectrum layout (see
-        ``repro.core.fftconv``): the rect rfft2 grid (``P``), the compact
-        Hermitian list (``"real"``), or the full spectrum (``"complex"``)."""
-        if spectrum == "rect":
-            return self.P
-        if spectrum == "complex":
-            return self.delta * self.delta
-        if spectrum == "real":
-            d = self.delta
-            return d * d // 2 + 2 if d % 2 == 0 else (d * d + 1) // 2
-        raise ValueError(f"unknown spectrum {spectrum!r}")
+    def freq_points(self) -> int:
+        """Stored frequency points of a tile's spectrum: the compact
+        Hermitian list ``repro.core.dft``'s folded matrices produce and
+        consume, delta^2/2 + 2 for even delta and (delta^2 + 1)/2 for odd
+        (130 at delta=16, just over half the full spectrum)."""
+        d = self.delta
+        return d * d // 2 + 2 if d % 2 == 0 else (d * d + 1) // 2
 
     # ---- cost model: what the plans run -----------------------------------
     # FLOPs count every multiply and add of the stage as the plans lower it
@@ -110,8 +97,7 @@ class ConvSpec:
                     + self.Cout * self.C * self.kh * self.kw
                     + self.B * self.Cout * self.Ho * self.Wo)
 
-    def cgemm_flops(self, three_m: bool = False,
-                    spectrum: str = "real") -> int:
+    def cgemm_flops(self, three_m: bool = False) -> int:
         """Per point: 3M is three real matmuls, the operand sums Dr + Di
         and Gr + Gi, and three output subtractions; 4M four matmuls and
         two output adds."""
@@ -120,36 +106,29 @@ class ConvSpec:
             per_point = 6 * M * C * Co + M * C + C * Co + 3 * M * Co
         else:
             per_point = 8 * M * C * Co + 2 * M * Co
-        return self.freq_points(spectrum) * per_point
+        return self.freq_points() * per_point
 
-    def _tile_dft_flops(self, spectrum: str) -> tuple:
-        """(forward per real delta x delta tile, inverse per output tile)."""
-        d = self.delta
-        if spectrum == "real":
-            # folded: one (d*d, P) matmul per plane forward; zr @ Kr +
-            # zi @ Ki against the cropped (P, t_h*t_w) mats inverse
-            P, t = self.freq_points("real"), self.t_h * self.t_w
-            return 4 * d * d * P, 4 * P * t + t
-        # separable row-then-column chain (rect and complex layouts)
-        dh = self.delta_h
-        return (2 * d * d * d * 2 + 4 * 2 * d * d * dh,
-                4 * 2 * d * d * dh + 2 * 2 * d * dh * d)
+    def _tile_dft_flops(self) -> tuple:
+        """(forward per real delta x delta tile, inverse per output tile):
+        one (d*d, P) matmul per plane forward; zr @ Kr + zi @ Ki against
+        the cropped (P, t_h*t_w) mats inverse."""
+        d, P, t = self.delta, self.freq_points(), self.t_h * self.t_w
+        return 4 * d * d * P, 4 * P * t + t
 
-    def stage_flops(self, spectrum: str = "real",
-                    three_m: bool = True) -> dict:
+    def stage_flops(self, three_m: bool = True) -> dict:
         """FLOPs of each FFT stage (stage 2 once per weight version)."""
-        fwd, inv = self._tile_dft_flops(spectrum)
+        fwd, inv = self._tile_dft_flops()
         return {"input_transform": self.M * self.C * fwd,
                 "kernel_transform": self.C * self.Cout * fwd,
-                "cgemm": self.cgemm_flops(three_m=three_m, spectrum=spectrum),
+                "cgemm": self.cgemm_flops(three_m=three_m),
                 "output_inverse": self.M * self.Cout * inv}
 
-    def stage_bytes(self, spectrum: str = "real") -> dict:
+    def stage_bytes(self) -> dict:
         """HBM bytes of each FFT stage a prepared forward runs: stage 1
         reads x, writes and reads the overlap-save tiles and writes the
         spectrum; stage 3 reads the spectrum and G and writes Z; stage 4
         reads Z and writes y."""
-        P, d = self.freq_points(spectrum), self.delta
+        P, d = self.freq_points(), self.delta
         x = self.B * self.C * self.H * self.W
         y = self.B * self.Cout * self.Ho * self.Wo
         D, G, Z = (2 * P * self.M * self.C, 2 * P * self.C * self.Cout,

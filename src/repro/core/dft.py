@@ -11,10 +11,14 @@ precomputed DFT matrices:
 where delta_h = delta//2 + 1 and Wr folds the Hermitian-redundant columns
 back with weight 2 (columns 0 and Nyquist with weight 1).
 
-For the compact Hermitian layout both factors fold further, into ONE
-matrix per direction (``compact_forward_mat``, ``compact_inverse_mats``,
+The engine stores a tile's spectrum as the compact Hermitian list and
+folds both factors further, into ONE matrix per direction
+(``compact_forward_mat``, ``compact_inverse_mats``,
 ``cropped_inverse_mats``): a tile's delta^2 pixels contract against it
-in a single matmul, so no separable intermediate is written.
+in a single matmul, so no separable intermediate is written.  The
+separable functions (``rfft2_tiles``, ``irfft2_tiles``,
+``pack_half_spectrum``, ``unpack_half_spectrum``) are the references the
+folded matrices and the ``dft_tile`` kernels are checked against.
 
 All complex arithmetic is struct-of-arrays (separate real/imag planes);
 neither the MXU nor Pallas has a native complex dtype.
@@ -97,18 +101,9 @@ def irfft2_tiles(Zr, Zi, delta: int):
     return _ein("...hv,wv->...hw", Yr, Wr) - _ein("...hv,wv->...hw", Yi, Wi)
 
 
-def num_freq(delta: int) -> int:
-    """Number of stored complex frequency points P in the rfft2 layout."""
-    return delta * (delta // 2 + 1)
-
-
-def num_freq_full(delta: int) -> int:
-    """Frequency points in the full complex spectrum (``spectrum="complex"``)."""
-    return delta * delta
-
-
 def num_freq_real(delta: int) -> int:
-    """Frequency points in the compact Hermitian layout (``spectrum="real"``).
+    """Frequency points in the compact Hermitian layout (the width of the
+    folded matrices; ``ConvSpec.freq_points`` counts the same for a spec).
 
     The rect rfft2 layout (delta x delta_h) still stores u-redundant rows in
     its self-conjugate columns (v = 0, and v = delta/2 for even delta):
@@ -262,26 +257,3 @@ def unpack_half_spectrum(Zr, Zi, delta: int):
     Zr = jnp.take(Zr, src, axis=-1).reshape(shape)
     Zi = (jnp.take(Zi, src, axis=-1) * sgn.astype(Zi.dtype)).reshape(shape)
     return Zr, Zi
-
-
-def fft2_full_tiles(x, delta: int):
-    """Batched full fft2 of real tiles: (..., delta, delta) -> two
-    (..., delta, delta) planes (the ``spectrum="complex"`` twin)."""
-    Fr, Fi, *_ = dft_mats(delta)
-    Ar = _ein("uh,...hw->...uw", Fr, x)
-    Ai = _ein("uh,...hw->...uw", Fi, x)
-    Tr = _ein("...uw,vw->...uv", Ar, Fr) - _ein("...uw,vw->...uv", Ai, Fi)
-    Ti = _ein("...uw,vw->...uv", Ar, Fi) + _ein("...uw,vw->...uv", Ai, Fr)
-    return Tr, Ti
-
-
-def ifft2_full_tiles(Zr, Zi, delta: int):
-    """Batched full ifft2: two (..., delta, delta) planes -> real tiles.
-
-    Returns Re(Finv @ Z @ Finv^T); the imaginary part cancels for spectra of
-    real signals.
-    """
-    _, _, _, _, Fvr, Fvi, _, _ = dft_mats(delta)
-    Yr = _ein("hu,...uv->...hv", Fvr, Zr) - _ein("hu,...uv->...hv", Fvi, Zi)
-    Yi = _ein("hu,...uv->...hv", Fvr, Zi) + _ein("hu,...uv->...hv", Fvi, Zr)
-    return _ein("...hv,wv->...hw", Yr, Fvr) - _ein("...hv,wv->...hw", Yi, Fvi)

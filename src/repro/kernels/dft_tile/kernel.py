@@ -96,7 +96,7 @@ def _rinv_kernel(zr_ref, zi_ref, fvr_ref, fvi_ref, wr_ref, wi_ref,
 def _rinv_epilogue_kernel(zr_ref, zi_ref, kr_ref, ki_ref, b_ref, y_ref, *,
                           activation):
     """Compact-layout inverse tile DFT + bias/activation tail on the
-    VMEM-resident block (the ``spectrum="real"`` stage-4 fast path):
+    VMEM-resident block (the ``fft-pallas`` stage-4 tail on ``local``):
     (bt, P) planes @ the folded (P, delta*delta) inverse -> flat tiles."""
     dot = functools.partial(jnp.dot, precision=dot_precision(zr_ref.dtype),
                             preferred_element_type=jnp.float32)

@@ -179,7 +179,7 @@ class ServeEngine:
       make_layers: ``make_layers(batch)`` (or ``make_layers(batch,
         image=s)`` when the policy buckets image sizes) returning the
         ``NetworkConv`` sequence for one padded input shape.
-      params: layer-name -> kernel array mapping (``prepare_all``
+      params: layer-name -> kernel array mapping (``NetworkPlan.prepare``
         contract; biases etc. ride via the ``forward`` closure).
       policy: the ``BucketPolicy``.
       forward: ``forward(prepared_net, x) -> y`` executing one padded

@@ -120,6 +120,43 @@ def test_prepared_and_replicated_elide_kernel_boundary():
     repl.check().raise_if_failed()
 
 
+def _counted_bytes(form, prepared, itemsize):
+    """Collective operand bytes of ``_plan``'s geometry on a (1, 1) mesh,
+    written out: (2, 3, 18, 18) * (4, 3, 3, 3) at pad 1 is 2 x 2 tiles
+    of 14 outputs, so M = 2 * 4 rows; the compact spectrum at delta 16
+    has 130 points; every (re, im) pair counts twice."""
+    P, M, C, Co = 130, 8, 3, 4
+    if form == "wfft":
+        return 2 * P * M * Co * itemsize               # the hot psum pair
+    d = 2 * P * M * C * itemsize                       # D boundary
+    z = 2 * P * M * Co * itemsize                      # Z boundary
+    g = 0 if prepared or form == "nfft-rkt" \
+        else 2 * P * C * Co * 4                        # kernel, in f32
+    return d + z + g
+
+
+_BYTES_CASES = [(form, prepared, overlap, None)
+                for form in ("nfft", "nfft-rkt", "wfft")
+                for prepared in (False, True)
+                for overlap in ("off", "slab:2")] \
+    + [(form, False, "off", jnp.bfloat16)
+       for form in ("nfft", "nfft-rkt", "wfft")]
+
+
+@pytest.mark.parametrize("form,prepared,overlap,cdt", _BYTES_CASES)
+def test_collective_bytes_match_the_count(form, prepared, overlap, cdt):
+    """The traced collective bytes equal the geometry's count exactly, on
+    every sharded form; the exact-bytes rule holds."""
+    kw = {"overlap": overlap, "compute_dtype": cdt}
+    if form == "nfft-rkt":
+        kw["replicate_kernel_transform"] = True
+    p = analyze(_plan("fft-xla", form[:4], **kw), prepared=prepared)
+    want = _counted_bytes(form, prepared, 4 if cdt is None else 2)
+    assert p.collective_bytes == p.counted_collective_bytes == want
+    assert f"{form[:4]}-collective-bytes" not in \
+        [v.invariant for v in p.check().violations]
+
+
 def test_epilogue_delta_is_zero_everywhere():
     ep = Epilogue(bias=True, activation="silu", residual=True)
     p = analyze(_plan("fft-xla", "wfft", epilogue=ep))
@@ -203,6 +240,10 @@ def test_seeded_violation_is_caught(mode):
                               mesh=_mesh(), **kw))
     report = p.check()
     assert not report.ok
+    if mode == "rfft-unpacked":
+        # the rect half-plane ships 144 points where the count has 130
+        assert "nfft-collective-bytes" in \
+            [v.invariant for v in report.violations]
     with pytest.raises(AssertionError, match="plan-lint"):
         report.raise_if_failed()
 

@@ -112,9 +112,28 @@ def test_spec_signature_separates_geometry_and_constraints(tune_env):
     # kernel-transform placement changes the measured nfft pipeline
     assert s1 != autotune.spec_signature(
         X_SHAPE, K_SHAPE, padding=1, replicate_kernel_transform=True)
-    # a spectrum-pinned sweep must never answer for an unconstrained one
-    assert s1 != autotune.spec_signature(X_SHAPE, K_SHAPE, padding=1,
-                                         spectrum="complex")
+
+
+def test_parent_format_cache_entry_is_not_served(tune_env, monkeypatch):
+    """Entries written while the frequency layout was a tuning axis carry
+    a v3 key with a ``spec=`` field and may name ``spectrum="complex"``:
+    they must miss, never be served as a plan — whether the file is the
+    old version or the key alone is old."""
+    key = autotune.cache_key(X_SHAPE, K_SHAPE, padding=1)
+    old_key = key.replace(f"v{autotune.CACHE_VERSION}|", "v3|", 1) \
+        .replace("|ov=", "|spec=auto|ov=", 1)
+    entry = {"backend": "fft-pallas", "schedule": "local", "bm": 8,
+             "bn": 8, "bk": 8, "dft_bt": None, "spectrum": "complex",
+             "overlap": "off", "us_per_call": 1.0, "source": "seeded"}
+    monkeypatch.setenv("REPRO_AUTOTUNE", "0")
+    for version in (3, autotune.CACHE_VERSION):
+        tune_env.write_text(json.dumps(
+            {"version": version, "entries": {old_key: entry}}))
+        autotune.reset()
+        assert autotune.lookup(X_SHAPE, K_SHAPE, padding=1) is None
+        w = autotune.tune(X_SHAPE, K_SHAPE, padding=1)
+        assert w.source == "cost-model" and w.bm is None
+        assert autotune_info().hits == 0
 
 
 def test_failing_candidate_is_reported_not_skipped(tune_env, monkeypatch):
@@ -361,22 +380,6 @@ def test_candidates_cover_the_space_and_order_cheap_first(tune_env):
     pinned = autotune.candidates(spec, bm=8, bn=8, bk=8, dft_bt=32)
     assert all((c.bm, c.dft_bt) == (8, 32)
                for c in pinned if c.backend == "fft-pallas")
-
-
-def test_candidates_spectrum_axis(tune_env):
-    spec = autotune._make_spec(X_SHAPE, K_SHAPE, (1, 1), 16)
-    local = autotune.candidates(spec)
-    # FFT backends get both frequency layouts; direct has no spectrum
-    for be in ("fft-xla", "fft-pallas"):
-        assert {c.spectrum for c in local if c.backend == be} \
-            == {"real", "complex"}
-    assert all(c.spectrum == "real" for c in local if c.backend == "direct")
-    assert local[0].spectrum == "real"         # cost-model pick stays first
-    # pinning the spectrum collapses the axis (and drops direct for the
-    # complex-only sweep — plan_conv rejects direct+complex)
-    pinned = autotune.candidates(spec, spectrum="complex")
-    assert {c.spectrum for c in pinned} == {"complex"}
-    assert "direct" not in {c.backend for c in pinned}
 
 
 def test_plan_network_tuned_sweep_and_report(tune_env):

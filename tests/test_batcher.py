@@ -309,20 +309,3 @@ def test_plan_network_buckets_dedupe_report():
     # a callable layer factory needs buckets=
     with pytest.raises(TypeError, match="buckets"):
         plan_network(_layers, backend="fft-xla")
-
-
-def test_bucket_shims_warn_but_work():
-    from repro.conv import (bucket_report, plan_network_buckets,
-                            prepare_network_buckets)
-    with pytest.warns(DeprecationWarning, match="plan_network_buckets"):
-        nets = plan_network_buckets(_layers, (1, 2), backend="fft-xla")
-    assert tuple(nets) == (1, 2)
-    with pytest.warns(DeprecationWarning, match="bucket_report"):
-        rep = bucket_report(nets)
-    assert rep["n_buckets"] == 2
-    with pytest.warns(DeprecationWarning, match="prepare_network_buckets"):
-        prepared = prepare_network_buckets(nets, _params(),
-                                           weights_version=0)
-    assert tuple(prepared) == (1, 2)
-    with pytest.warns(DeprecationWarning, match="prepare_all"):
-        nets[1].prepare_all(_params(), weights_version=0)

@@ -49,13 +49,10 @@ SCHEDULES = ["nfft", "wfft"]
 
 @pytest.mark.parametrize("backend", BACKENDS)
 @pytest.mark.parametrize("schedule", SCHEDULES)
-@pytest.mark.parametrize("spectrum", ["real", "complex"])
 @pytest.mark.parametrize("batch", [4, 5])   # 5: odd remainder, slabs 3+2
-def test_overlap_matches_sequential_and_oracle(backend, schedule,
-                                               spectrum, batch):
+def test_overlap_matches_sequential_and_oracle(backend, schedule, batch):
     x, k = _rand((batch, 3, 12, 12), 1), _rand((4, 3, 3, 3), 2)
-    kw = dict(padding=1, backend=backend, schedule=schedule, mesh=_mesh(),
-              spectrum=spectrum)
+    kw = dict(padding=1, backend=backend, schedule=schedule, mesh=_mesh())
     seq = plan_conv(x.shape, k.shape, overlap="off", **kw)
     ovl = plan_conv(x.shape, k.shape, overlap="slab:2", **kw)
     assert seq.num_slabs == 1 and ovl.num_slabs == 2

@@ -322,11 +322,11 @@ def test_plan_metadata_and_flops():
     assert plan.out_shape == (2, 4, 20, 20)
     assert plan.differentiable
     assert plan.flops() == sum(plan.spec.stage_flops().values())
-    # the compact Hermitian layout is the default, and its CGEMM runs
-    # fewer points than the historical rect rfft2 grid
-    assert plan.spectrum == "real"
-    assert plan.spec.cgemm_flops(three_m=True) \
-        < plan.spec.cgemm_flops(three_m=True, spectrum="rect")
+    # the CGEMM runs one complex product per compact Hermitian point
+    s = plan.spec
+    assert s.freq_points() == 130
+    assert s.cgemm_flops(three_m=False) \
+        == 130 * (8 * s.M * s.C * s.Cout + 2 * s.M * s.Cout)
     direct = plan_conv((2, 8, 20, 20), (4, 8, 3, 3), padding=1,
                        backend="direct")
     assert direct.flops() == direct.spec.direct_flops()
@@ -356,18 +356,18 @@ def test_stage_flops_match_xla_cost_analysis(x_shape, k_shape, pad, three_m):
     from repro.core import fftconv as F
     from repro.core.cgemm import cgemm
     spec = plan_conv(x_shape, k_shape, padding=pad, backend="fft-xla").spec
-    P, M, C, Co = spec.freq_points("real"), spec.M, spec.C, spec.Cout
-    want = spec.stage_flops("real", three_m=three_m)
+    P, M, C, Co = spec.freq_points(), spec.M, spec.C, spec.Cout
+    want = spec.stage_flops(three_m=three_m)
     got = {
         "input_transform": _xla_flops(
-            lambda x: F.input_transform(x, spec, spectrum="real"), x_shape),
+            lambda x: F.input_transform(x, spec), x_shape),
         "kernel_transform": _xla_flops(
-            lambda k: F.kernel_transform(k, spec, spectrum="real"), k_shape),
+            lambda k: F.kernel_transform(k, spec), k_shape),
         "cgemm": _xla_flops(
             lambda a, b, c, d: cgemm(a, b, c, d, three_m=three_m),
             (P, M, C), (P, M, C), (P, C, Co), (P, C, Co)),
         "output_inverse": _xla_flops(
-            lambda zr, zi: F._folded_inverse(zr, zi, spec),
+            lambda zr, zi: F.output_inverse(zr, zi, spec),
             (P, M, Co), (P, M, Co)),
     }
     for stage, n in want.items():

@@ -1,8 +1,7 @@
-"""Real-input FFT (rfft) half-spectrum fast path.
+"""Real-input FFT (rfft) half-spectrum path.
 
-The compact Hermitian frequency layout (``spectrum="real"``, the planner
-default) must agree with the full-spectrum twin (``spectrum="complex"``)
-and the direct oracle on every registered backend x schedule pair, for
+The compact Hermitian frequency layout the FFT engine runs must agree
+with the direct oracle on every registered backend x schedule pair, for
 even AND odd tile sizes (the DC/Nyquist self-conjugate bins differ), and
 its plan-level VJP must match the oracle gradients.
 """
@@ -20,7 +19,7 @@ from repro.compat import make_mesh
 from repro.conv import Epilogue, plan_conv
 from repro.conv.registry import backend_schedule_pairs
 from repro.core import conv2d_direct
-from repro.core.dft import num_freq_full, num_freq_real
+from repro.core.dft import num_freq_real
 
 
 def _rand(shape, seed=0):
@@ -38,7 +37,7 @@ def _assert_close(y, y0, tol=2e-4):
 
 
 # --------------------------------------------------------------------------
-# Parity: real vs complex vs the direct oracle, every backend x schedule
+# Parity with the direct oracle, every backend x schedule
 # --------------------------------------------------------------------------
 
 @pytest.mark.parametrize("backend,schedule", backend_schedule_pairs())
@@ -48,31 +47,23 @@ def test_rfft_parity_every_backend_schedule(backend, schedule):
     y0 = conv2d_direct(x, k, padding=1)
     plan = plan_conv(x.shape, k.shape, padding=1, backend=backend,
                      schedule=schedule, mesh=mesh)
-    assert plan.spectrum == "real"             # compact layout is default
     _assert_close(plan(x, k), y0)
-    if backend == "direct":
-        return                                 # direct has no spectrum
-    twin = plan_conv(x.shape, k.shape, padding=1, backend=backend,
-                     schedule=schedule, mesh=mesh, spectrum="complex")
-    assert twin.spectrum == "complex"
-    _assert_close(twin(x, k), y0)
 
 
 @pytest.mark.parametrize("delta,hw", [(16, 18), (16, 23), (15, 19),
                                       (8, 14), (5, 11)])
-@pytest.mark.parametrize("spectrum", ["real", "complex"])
-def test_rfft_even_and_odd_tile_sizes(delta, hw, spectrum):
+def test_rfft_even_and_odd_tile_sizes(delta, hw):
     """Odd delta has NO Nyquist column — the self-conjugate fold weights
-    differ from the even case and both layouts must still invert."""
+    differ from the even case and the compact layout must still invert."""
     x, k = _rand((1, 2, hw, hw), 3), _rand((3, 2, 3, 3), 4)
     y0 = conv2d_direct(x, k, padding=1)
     plan = plan_conv(x.shape, k.shape, padding=1, delta=delta,
-                     backend="fft-xla", spectrum=spectrum)
+                     backend="fft-xla")
     _assert_close(plan(x, k), y0)
 
 
 def test_rfft_fused_epilogue_parity():
-    """fft-pallas/local/real runs stage 4 through the fused irfft+epilogue
+    """fft-pallas/local runs stage 4 through the fused irfft+epilogue
     dft_tile kernel — bias and activation must match the oracle."""
     x, k = _rand((2, 3, 18, 18), 5), _rand((4, 3, 3, 3), 6)
     b = _rand((4,), 7)
@@ -80,7 +71,6 @@ def test_rfft_fused_epilogue_parity():
                      + b[None, :, None, None])
     plan = plan_conv(x.shape, k.shape, padding=1, backend="fft-pallas",
                      epilogue=Epilogue(bias=True, activation="relu"))
-    assert plan.spectrum == "real"
     _assert_close(plan(x, k, bias=b), y0)
 
 
@@ -88,11 +78,9 @@ def test_rfft_fused_epilogue_parity():
 # Gradients through the plan-level VJP
 # --------------------------------------------------------------------------
 
-@pytest.mark.parametrize("spectrum", ["real", "complex"])
-def test_rfft_gradients_match_oracle(spectrum):
+def test_rfft_gradients_match_oracle():
     x, k = _rand((1, 2, 14, 14), 8), _rand((3, 2, 3, 3), 9)
-    plan = plan_conv(x.shape, k.shape, padding=1, backend="fft-xla",
-                     spectrum=spectrum)
+    plan = plan_conv(x.shape, k.shape, padding=1, backend="fft-xla")
 
     def loss(f):
         return lambda a, b: jnp.sum(f(a, b) ** 2)
@@ -103,43 +91,6 @@ def test_rfft_gradients_match_oracle(spectrum):
         argnums=(0, 1))(x, k)
     _assert_close(gx, gx0, tol=2e-3)
     _assert_close(gk, gk0, tol=2e-3)
-
-
-# --------------------------------------------------------------------------
-# Plan/prepared caching: spectrum is part of the identity
-# --------------------------------------------------------------------------
-
-def test_spectrum_is_in_the_plan_cache_key():
-    kw = dict(padding=1, backend="fft-xla")
-    real = plan_conv((1, 2, 16, 16), (2, 2, 3, 3), **kw)
-    real2 = plan_conv((1, 2, 16, 16), (2, 2, 3, 3), **kw, spectrum="real")
-    cplx = plan_conv((1, 2, 16, 16), (2, 2, 3, 3), **kw, spectrum="complex")
-    assert real is real2                       # "auto" == "real" == default
-    assert real is not cplx and cplx.spectrum == "complex"
-
-
-def test_prepared_state_tracks_spectrum():
-    """prepare() bakes the transformed-kernel slab whose P axis depends on
-    the layout — a real-prepared state must never serve a complex plan."""
-    x, k = _rand((1, 2, 16, 16), 10), _rand((2, 2, 3, 3), 11)
-    y0 = conv2d_direct(x, k, padding=1)
-    kw = dict(padding=1, backend="fft-xla")
-    real = plan_conv(x.shape, k.shape, **kw).prepare(k)
-    cplx = plan_conv(x.shape, k.shape, **kw, spectrum="complex").prepare(k)
-    p_real = jax.tree_util.tree_leaves(real.state)[0].shape[0]
-    p_cplx = jax.tree_util.tree_leaves(cplx.state)[0].shape[0]
-    assert p_real == num_freq_real(16) and p_cplx == num_freq_full(16)
-    _assert_close(real(x), y0)
-    _assert_close(cplx(x), y0)
-
-
-def test_direct_backend_rejects_complex_spectrum():
-    with pytest.raises(ValueError, match="spectrum"):
-        plan_conv((1, 2, 16, 16), (2, 2, 3, 3), padding=1,
-                  backend="direct", spectrum="complex")
-    with pytest.raises(ValueError, match="unknown spectrum"):
-        plan_conv((1, 2, 16, 16), (2, 2, 3, 3), padding=1,
-                  backend="fft-xla", spectrum="rect")
 
 
 # --------------------------------------------------------------------------
@@ -174,15 +125,21 @@ def test_tile_irfft_pallas_ignores_trailing_padding():
     _assert_close(yp, x, tol=1e-4)
 
 
-def test_tile_irfft_epilogue_pallas_fuses_bias_relu():
+@pytest.mark.parametrize("activation", ["none", "relu", "gelu", "silu"])
+def test_tile_irfft_epilogue_pallas_fuses_bias_relu(activation):
+    """The fused stage-4 tail of fft-pallas/local: inverse + bias +
+    every registered activation in one kernel pass."""
+    from repro.conv.epilogue import ACTIVATIONS
     from repro.kernels.dft_tile import (
         tile_irfft_epilogue_pallas, tile_irfft_ref, tile_rfft_pallas,
     )
     x = _rand((6, 16, 16), 14)
     b = _rand((6,), 15)
     Tr, Ti = tile_rfft_pallas(x, delta=16)
-    y = tile_irfft_epilogue_pallas(Tr, Ti, b, activation="relu", delta=16)
-    y0 = jax.nn.relu(tile_irfft_ref(Tr, Ti, 16) + b[:, None, None])
+    y = tile_irfft_epilogue_pallas(Tr, Ti, b, activation=activation,
+                                   delta=16)
+    y0 = ACTIVATIONS[activation](tile_irfft_ref(Tr, Ti, 16)
+                                 + b[:, None, None])
     _assert_close(y, y0, tol=1e-4)
 
 

@@ -43,18 +43,21 @@ err = float(jnp.max(jnp.abs(y - y0))) / float(jnp.max(jnp.abs(y0)))
 assert err < 1e-4, ("nfft_repG", err)
 hlo = f.lower(x, k).compile().as_text()
 assert "all-reduce" not in hlo, "repG must not introduce an all-reduce"
-# the full-spectrum twin must agree with the default compact layout, and
-# the compact plan must move at most 0.55x the twin's collective bytes
-f = jax.jit(plan_conv(x.shape, k.shape, schedule="nfft", mesh=mesh,
-                      padding=1, spectrum="complex"))
-y = f(x, k)
-err = float(jnp.max(jnp.abs(y - y0))) / float(jnp.max(jnp.abs(y0)))
-assert err < 1e-4, ("complex", err)
+# the collectives move exactly the compact spectrum's bytes per rank:
+# P = 130 points (132 padded to the 4-way model axis for nfft), 2x2
+# tiles of 14 outputs, 2 images and 2 of the 8 channels per rank, re/im
 from repro.conv import analyze
-prof = analyze(plan_conv(x.shape, k.shape, schedule="nfft", mesh=mesh,
-                         padding=1))
-assert prof.spectrum == "real", prof.spectrum
-assert prof.spectrum_delta["ratio"] <= 0.55, prof.spectrum_delta
+rows = 2 * 4
+want = {"nfft": 2 * 132 * rows * 2 * 4            # D boundary
+                + 2 * 33 * rows * 8 * 4            # Z boundary
+                + 2 * 132 * 8 * 2 * 4,             # kernel boundary
+        "wfft": 2 * 130 * rows * 8 * 4}            # the hot psum pair
+for strat, nbytes in want.items():
+    prof = analyze(plan_conv(x.shape, k.shape, schedule=strat, mesh=mesh,
+                             padding=1))
+    assert prof.collective_bytes == prof.counted_collective_bytes == nbytes, \
+        (strat, prof.collective_bytes, prof.counted_collective_bytes)
+    assert prof.check().ok, prof.check().violations
 print("DIST_OK")
 """
 

@@ -80,7 +80,7 @@ def test_spec_geometry():
     spec = make_spec((4, 8, 56, 56), (16, 8, 3, 3), padding=1)
     assert spec.Ho == 56 and spec.Wo == 56
     assert spec.t_h == 14 and spec.X == 4 and spec.D == 4
-    assert spec.P == 16 * 9 and spec.M == 4 * 16
+    assert spec.freq_points() == 16 * 16 // 2 + 2 and spec.M == 4 * 16
 
 
 if HAVE_HYPOTHESIS:
@@ -128,20 +128,5 @@ def test_pallas_backend_matches_direct():
     x, k = _rand((2, 8, 20, 20), 11), _rand((8, 8, 3, 3), 12)
     y = plan_conv(x.shape, k.shape, padding=1, backend="fft-pallas")(x, k)
     y0 = conv2d_direct(x, k, padding=1)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(y0),
-                               rtol=3e-4, atol=3e-4)
-
-
-def test_deprecated_shims_still_work():
-    """Old entry points warn but route through the same planned paths."""
-    import repro.core as core
-    x, k = _rand((1, 3, 12, 12), 13), _rand((2, 3, 3, 3), 14)
-    y0 = conv2d_direct(x, k, padding=1)
-    with pytest.warns(DeprecationWarning):
-        y = core.fft_conv2d(x, k, padding=1)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(y0),
-                               rtol=2e-4, atol=2e-4)
-    with pytest.warns(DeprecationWarning):
-        y = core.fft_conv2d_pallas(x, k, padding=1)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y0),
                                rtol=3e-4, atol=3e-4)

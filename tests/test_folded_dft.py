@@ -1,12 +1,13 @@
-"""The folded tile DFT of ``spectrum="real"``.
+"""The folded tile DFT of the FFT engine.
 
-Stages 1, 2 and 4 of a real-spectrum plan apply each tile's 2-D DFT as one
-matmul against a fixed matrix (``repro.core.dft``): the forward folds the
-compact-Hermitian packing in, the inverse folds the conj-mirror scatter and
-the overlap-save crop in.  They must equal the separable chain they
-replace — ``pack_half_spectrum(*rfft2_tiles(x))`` and
+Stages 1, 2 and 4 apply each tile's 2-D DFT as one matmul against a fixed
+matrix (``repro.core.dft``): the forward folds the compact-Hermitian
+packing in, the inverse folds the conj-mirror scatter and the overlap-save
+crop in.  They must equal the separable chain they replace —
+``pack_half_spectrum(*rfft2_tiles(x))`` and
 ``irfft2_tiles(*unpack_half_spectrum(z))`` — for even and odd tile sizes,
-and every planned real layer must record that it took the folded form.
+the matrices' width must be ``ConvSpec.freq_points()``, and every planned
+layer must carry that many points through its stages.
 """
 import jax
 import jax.numpy as jnp
@@ -14,13 +15,12 @@ import numpy as np
 import pytest
 
 from repro.compat import make_mesh
-from repro.conv import Epilogue, plan_conv, stage_trace
+from repro.conv import Epilogue, plan_conv, stages
 from repro.conv.analyze import analyze, seeded_violation
 from repro.core import dft, fftconv as F
 from repro.core.fftconv import make_spec
 
 DELTAS = [5, 8, 16]
-FOLDED = ("transform_form", "folded")
 
 
 def _rand(shape, seed=0):
@@ -57,7 +57,7 @@ def test_folded_input_transform_equals_separable(delta):
     the (P, M, C) the CGEMM reads."""
     spec = _spec(delta)
     x = _rand((spec.B, spec.C, spec.H, spec.W), 1)
-    Dr, Di = F.input_transform(x, spec, spectrum="real")
+    Dr, Di = F.input_transform(x, spec)
     tiles = F.extract_tiles(x, spec)                  # (B, C, X, Dl, d, d)
     Tr, Ti = dft.pack_half_spectrum(*dft.rfft2_tiles(tiles, delta), delta)
     P = Tr.shape[-1]
@@ -79,7 +79,7 @@ def test_folded_inverse_equals_unpacked_irfft2(delta, pad_rows):
     P = dft.num_freq_real(delta)
     Zr = _rand((P + pad_rows, spec.M, spec.Cout), 2)
     Zi = _rand((P + pad_rows, spec.M, spec.Cout), 3)
-    y = F.output_inverse(Zr, Zi, spec, spectrum="real")
+    y = F.output_inverse(Zr, Zi, spec)
     Ur, Ui = dft.unpack_half_spectrum(F.z_to_flat_tiles(Zr, spec, P),
                                       F.z_to_flat_tiles(Zi, spec, P), delta)
     y0 = F.assemble_output_tiles(dft.irfft2_tiles(Ur, Ui, delta), spec)
@@ -109,11 +109,23 @@ def test_rect_folded_forward_keeps_every_half_plane_point():
     _assert_close(T[:, P:], Ti.reshape(3, -1), 1e-4)
 
 
+@pytest.mark.parametrize("delta", [5, 8, 15, 16, 32])
+def test_freq_points_is_the_matrices_width(delta):
+    """``ConvSpec.freq_points()`` counts the points the folded matrices
+    produce (forward columns) and consume (inverse rows)."""
+    spec = _spec(delta, H=2 * delta, W=2 * delta)
+    assert spec.freq_points() \
+        == dft.compact_forward_mat(delta).shape[1] // 2 \
+        == dft.cropped_inverse_mats(delta, spec.t_h, spec.t_w)[0].shape[0]
+
+
 @pytest.mark.parametrize("delta", DELTAS)
 @pytest.mark.parametrize("backend", ["fft-xla", "fft-pallas"])
-def test_planned_real_layer_records_the_folded_form(delta, backend):
-    """Stage 1 and stage 4 of a planned real layer each record
-    ``("transform_form", "folded")``; nothing records the separable form."""
+def test_planned_real_layer_records_the_folded_form(delta, backend,
+                                                    monkeypatch):
+    """Stage 1's output, the CGEMM's operands and stage 4's input of a
+    planned layer all carry ``spec.freq_points()`` points, as does the
+    prepared kernel."""
     spec = _spec(delta)
     plan = plan_conv((spec.B, spec.C, spec.H, spec.W),
                      (spec.Cout, spec.C, spec.kh, spec.kw), padding=1,
@@ -121,43 +133,37 @@ def test_planned_real_layer_records_the_folded_form(delta, backend):
                      epilogue=Epilogue(bias=True, activation="relu"),
                      cache=False)
     prepared = plan.prepare(_rand((spec.Cout, spec.C, spec.kh, spec.kw), 5))
+    points = {}
+
+    def record(name, points_of):
+        """Wrap a stage op to note the point count it sees."""
+        fn = getattr(stages, name)
+
+        def wrapped(*args, **kw):
+            out = fn(*args, **kw)
+            points.setdefault(name, set()).add(int(points_of(args, out)))
+            return out
+        monkeypatch.setattr(stages, name, wrapped)
+
+    record("stage_input_transform", lambda args, out: out[0].shape[0])
+    record("stage_cgemm", lambda args, out: args[2].shape[0])       # Gr
+    record("stage_output_inverse", lambda args, out: args[0].shape[0])
     x = _rand((spec.B, spec.C, spec.H, spec.W), 6)
-    with stage_trace() as counts:
-        jax.make_jaxpr(lambda x, b: prepared(x, bias=b))(
-            x, jnp.ones((spec.Cout,)))
-    forms = {k: v for k, v in counts.items()
-             if isinstance(k, tuple) and k[0] == "transform_form"}
-    assert forms == {FOLDED: 2}
-    assert analyze(prepared).transform_forms == ("folded",)
-
-
-def test_complex_spectrum_keeps_the_separable_form():
-    from repro.conv import stages
-    spec = _spec(16)
-    with stage_trace() as counts:
-        jax.make_jaxpr(lambda x: stages.stage_input_transform(
-            x, spec, "complex"))(_rand((spec.B, spec.C, spec.H, spec.W)))
-    assert counts[("transform_form", "separable")] == 1
-    assert FOLDED not in counts
-
-
-def test_real_spectrum_folded_invariant_bites(monkeypatch):
-    """Plan-lint refuses a real-spectrum plan whose stages report another
-    form than the folded one."""
-    plan = plan_conv((2, 4, 22, 22), (4, 4, 3, 3), padding=1,
-                     backend="fft-xla", schedule="local", cache=False)
-    assert analyze(plan).check().ok
-    monkeypatch.setattr(F, "transform_form", lambda spectrum: "separable")
-    report = analyze(plan).check()
-    assert [v.invariant for v in report.violations] == \
-        ["real-spectrum-folded"]
+    jax.make_jaxpr(lambda x, b: prepared(x, bias=b))(
+        x, jnp.ones((spec.Cout,)))
+    P = spec.freq_points()
+    assert points == {"stage_input_transform": {P}, "stage_cgemm": {P},
+                      "stage_output_inverse": {P}}
+    assert {leaf.shape[0] for leaf in
+            jax.tree_util.tree_leaves(prepared.state)} == {P}
+    assert analyze(prepared).check().ok
 
 
 @pytest.mark.parametrize("schedule,invariant", [
-    ("nfft", "nfft-rfft-halves-a2a"), ("wfft", "wfft-rfft-halves-psum")])
+    ("nfft", "nfft-collective-bytes"), ("wfft", "wfft-collective-bytes")])
 def test_rfft_unpacked_seed_trips_the_halves_invariants(schedule, invariant):
     """The seeded rect-layout folded forward ships the redundant rows: the
-    bytes-ratio invariant of each sharded schedule must fail."""
+    exact collective-bytes invariant of each sharded schedule must fail."""
     mesh = make_mesh((1, 1), ("data", "model"))
     with seeded_violation("rfft-unpacked"):
         p = analyze(plan_conv((2, 4, 22, 22), (4, 4, 3, 3), padding=1,

@@ -115,15 +115,14 @@ VCONV12 = ((32, 64, 224, 224), (64, 64, 3, 3))     # Vconv1.2 at batch 32
 
 def _stage1(spec):
     from repro.core import fftconv as F
-    return (lambda x: F.input_transform(x, spec, spectrum="real"),
+    return (lambda x: F.input_transform(x, spec),
             [(spec.B, spec.C, spec.H, spec.W)])
 
 
 def _stage4(spec):
     from repro.core import fftconv as F
     z = (P_REAL, spec.M, spec.Cout)
-    return (lambda zr, zi: F.output_inverse(zr, zi, spec, spectrum="real"),
-            [z, z])
+    return (lambda zr, zi: F.output_inverse(zr, zi, spec), [z, z])
 
 
 # stage -> (builder, most bytes the compiled stage may access): the
@@ -137,8 +136,8 @@ FOLDED_STAGES = {"input_transform": (_stage1, 20e9),
 
 @pytest.mark.parametrize("stage", sorted(FOLDED_STAGES))
 def test_folded_stage_compiles_for_tpu(one_chip, stage):
-    """Stages 1 and 4 of Vconv1.2 (``spectrum="real"``) at the chip's
-    size: the tile DFT as one folded matmul per tile."""
+    """Stages 1 and 4 of Vconv1.2 at the chip's size: the tile DFT as one
+    folded matmul per tile."""
     from repro.core import fftconv as F
     build, max_bytes = FOLDED_STAGES[stage]
     fn, shapes = build(F.make_spec(*VCONV12, padding=1))
